@@ -19,6 +19,24 @@ import numpy as np
 ROWS: List[str] = []
 
 
+def use_compile_cache() -> str:
+    """Point JAX's persistent compile cache at a fixed place; returns it.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins and JAX reads it itself.
+    Otherwise the cache goes to ``<checkout>/.jax_cache`` (git-ignored): a
+    fixed path, since the path is part of the cache key.  Call it before
+    the first compile."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def emit(name: str, value: float, unit: str, derived: str = "") -> None:
     row = f"{name},{value:.6g},{unit},{derived}"
     ROWS.append(row)

@@ -68,6 +68,7 @@ def _smoke() -> int:
     walltime).
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # the pytest child never takes the chip
     src = os.path.join(_REPO_ROOT, "src")
     env["PYTHONPATH"] = src + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")

@@ -13,7 +13,7 @@ and collects every legality and hazard finding as diagnostics:
 * **decomposition legality** (WLK22x) -- ``redistribute``/``ownership``
   axis vs the declared dataset rank, empty/uneven blocks, and the Pallas
   lane-width hint (the pack kernels tile 128 lanes; for flattened N-D
-  plans the effective tile is ``tile_rows * inner``).
+  plans a column tile spans ``lcm(inner, 128)`` columns).
 
 Rank/shape checks key on *optional* dataset hints the runtime ignores::
 
@@ -547,8 +547,8 @@ def _check_decomposition(graph, add, ploc) -> None:
                             f"dataset {dname!r} flattened inner extent "
                             f"{inner} (shape {list(shape)} after axis "
                             f"{axis}) is not a 128-lane multiple; the pack "
-                            f"kernel pads each tile_rows*{inner} tile to "
-                            f"128 lanes",
+                            f"kernel's lane-aligned tiles span "
+                            f"lcm({inner}, 128) columns and pad the tail",
                             line=line, task=name, port=port.filename)
                     # WLK225/226: prove the compiled reshard plan for this
                     # edge covers every destination element exactly once

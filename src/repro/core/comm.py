@@ -19,9 +19,10 @@ so the code is, again, identical in both settings.
 subsystem (paper §3.4): the driver wires each task's declared ``RedistSpec``s
 onto the communicator, so task code reshards a device array / numpy array /
 received Dataset into its per-rank blocks with ONE call -- no plan objects,
-no executor choice.  Device-resident buffers (any rank, global extent or a
-received slab) go through the Pallas pack kernels -- rank>2 plans flatten
-their non-decomposed axes onto the 2-D kernels; host buffers and genuinely
+no executor choice.  Device buffers (any rank, global extent or a received
+slab; a sharded one is first gathered onto one of its own devices) go
+through the Pallas pack kernels -- rank>2 plans flatten their
+non-decomposed axes onto the 2-D kernels; host buffers and genuinely
 cross-axis N-D decompositions take the numpy scatter executors.  Plans come
 from the process-wide ``PlanCache``.
 """
@@ -226,7 +227,7 @@ class TaskComm:
 
     def reshard(self, data, spec: Any = None, *, port: Optional[str] = None,
                 src: Optional[Sequence[Any]] = None, ranks: Any = "mine",
-                tile_rows: int = 8, prefer: str = "auto") -> List[Any]:
+                prefer: str = "auto") -> List[Any]:
         """Reshard an array (or received Dataset) into per-rank blocks.
 
         The one-call face of the M->N subsystem: resolves the task's
@@ -250,7 +251,6 @@ class TaskComm:
         ranks:  ``"mine"`` (this instance's logical ranks -- the default),
                 ``"all"`` (every dst rank of the full decomposition), or an
                 explicit iterable of dst rank ids.
-        tile_rows: pack-kernel tile extent along the decomposed axis.
         prefer: ``"auto"`` | ``"pack"`` (raise if the kernel path cannot
                 serve) | ``"numpy"``.
 
@@ -262,9 +262,11 @@ class TaskComm:
         device) whose plan is decomposed along a single axis -- any rank
         (rank>2 plans flatten onto the 2-D kernels, see
         ``redistribute.PackGeometry``), over the global extent OR a received
-        slab (gathers then run in slab-local source coordinates).  Only
-        host-resident data and genuinely cross-axis N-D decompositions take
-        the numpy scatter executors.
+        slab (gathers then run in slab-local source coordinates).  A Mosaic
+        kernel runs on one device, so a buffer sharded over several is first
+        copied onto the first device of its own sharding, device to device.
+        Only host-resident data and genuinely cross-axis N-D decompositions
+        take the numpy scatter executors.
         """
         import numpy as np
 
@@ -334,7 +336,9 @@ class TaskComm:
         # Probe the READ BUFFER, not the wrapper: a Dataset backed by a
         # device array reshards on the kernel path exactly like a raw
         # jax.Array (checking `data` here used to silently drop every
-        # device-resident Dataset onto the numpy executors).
+        # device-resident Dataset onto the numpy executors).  A sharded
+        # buffer takes the kernels too: the executor gathers it onto one of
+        # its own devices (a host round trip here would hide the device).
         is_jax = False
         if prefer != "numpy":
             try:
@@ -361,8 +365,8 @@ class TaskComm:
         tr = self.tracer
         t0 = time.monotonic()
         if can_pack:
-            out = execute_pack_jax_all(plan, arr, tile_rows=tile_rows,
-                                       slab_box=slab_box, ranks=wanted)
+            out = execute_pack_jax_all(plan, arr, slab_box=slab_box,
+                                       ranks=wanted)
         else:
             np_arr = np.asarray(arr)
             if slab_box is not None:

@@ -309,27 +309,40 @@ class Wilkins:
         self, devices: Optional[Sequence[Any]]
     ) -> Dict[Tuple[str, int], Optional[List[Any]]]:
         """Slice the global device list into disjoint restricted worlds,
-        proportionally to nprocs (the PMPI-partitioning analogue)."""
-        groups: Dict[Tuple[str, int], Optional[List[Any]]] = {}
-        instances: List[Tuple[str, int, int]] = []  # (task, inst, nprocs)
+        proportionally to nprocs (the PMPI-partitioning analogue).
+
+        Every instance gets at least one device; the rest go by largest
+        remainder of its nprocs share.  With fewer devices than instances
+        the groups cannot be disjoint: each instance past the last device
+        shares that device."""
+        instances: List[Tuple[str, int]] = []
+        nprocs: List[int] = []
         for name, t in self.graph.tasks.items():
             for i in range(t.task_count):
-                instances.append((name, i, t.nprocs))
+                instances.append((name, i))
+                nprocs.append(t.nprocs)
         if devices is None:
-            for name, i, _ in instances:
-                groups[(name, i)] = None
-            return groups
+            return {key: None for key in instances}
         devices = list(devices)
-        total_procs = sum(n for _, _, n in instances) or 1
+        n_dev, n_inst = len(devices), len(instances)
+        if n_dev < n_inst:
+            return {key: devices[min(k, n_dev - 1):][:1]
+                    for k, key in enumerate(instances)}
+        total = sum(nprocs) or 1
+        quota = [n_dev * n / total for n in nprocs]
+        share = [max(1, int(q)) for q in quota]
+        while sum(share) > n_dev:  # the minimum of one pushed us over
+            k = max((k for k in range(n_inst) if share[k] > 1),
+                    key=lambda k: share[k] - quota[k])
+            share[k] -= 1
+        for k in sorted(range(n_inst), key=lambda k: share[k] - quota[k])[
+                : n_dev - sum(share)]:
+            share[k] += 1
+        groups: Dict[Tuple[str, int], Optional[List[Any]]] = {}
         off = 0
-        for k, (name, i, n) in enumerate(instances):
-            share = max(1, (len(devices) * n) // total_procs)
-            if k == len(instances) - 1:
-                grp = devices[off:]
-            else:
-                grp = devices[off : off + share]
-            off = min(off + share, len(devices) - (len(instances) - 1 - k))
-            groups[(name, i)] = grp or devices[-1:]
+        for key, n in zip(instances, share):
+            groups[key] = devices[off : off + n]
+            off += n
         return groups
 
     # ------------------------------------------------------------ wiring

@@ -37,6 +37,7 @@ counts from the consumer's YAML) the driver wires from the workflow graph.
 
 from __future__ import annotations
 
+import math
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass
@@ -63,6 +64,7 @@ __all__ = [
     "redistribute_cached",
     "execute_pack_jax",
     "execute_pack_jax_all",
+    "pack_tiling",
     "gather_to_writers",
     "reshard_jax",
 ]
@@ -413,7 +415,7 @@ class CompiledPlan:
         return self.axis_runs(dst_rank, 1)
 
     def pack_tiles(
-        self, dst_rank: int, tile_rows: int = 8, mode: str = "rows",
+        self, dst_rank: int, tile: int = 8, mode: str = "rows",
         slab_start: int = 0, slab_extent: Optional[int] = None,
     ) -> Tuple[np.ndarray, Tuple[Tuple[int, int], ...]]:
         """Lower dst_rank's runs to pack-kernel tile offsets (cached).
@@ -421,9 +423,10 @@ class CompiledPlan:
         Returns ``(tile_offsets, segments)``: the int32 source tile index per
         output tile (the kernel's scalar-prefetch operand) and, per run,
         ``(offset_in_packed_output, count)`` to trim the tile padding back to
-        the exact rows (``mode="rows"``) or columns (``mode="cols"``).  All
-        quantities are in *decomposed-axis units* -- the executor scales by
-        ``PackGeometry.scale`` when the plan is a flattened N-D one.
+        the exact rows (``mode="rows"``) or columns (``mode="cols"``).
+        ``tile`` and both segment quantities are in units of the flattened
+        2-D kernel frame: a run of ``cnt`` indices along the decomposed axis
+        covers ``cnt * PackGeometry.scale`` frame columns in cols mode.
 
         ``slab_start`` / ``slab_extent`` shift the runs into slab-local
         source coordinates: a consumer holding only its received slab (whose
@@ -434,7 +437,7 @@ class CompiledPlan:
         clamped out-of-bounds tile DMAs would silently corrupt the block.
         """
         geom = self._resolve_geometry(mode)
-        key = (dst_rank, tile_rows, mode, slab_start, slab_extent)
+        key = (dst_rank, tile, mode, slab_start, slab_extent)
         with self._pack_lock:
             hit = self._pack_cache.get(key)
         if hit is not None:
@@ -452,9 +455,10 @@ class CompiledPlan:
                     f"the slab covers [{slab_start}, "
                     f"{slab_start + (slab_extent if slab_extent is not None else 0)}"
                     f"); the slab does not cover this rank")
-            t0 = start // tile_rows
-            t1 = -(-(start + cnt) // tile_rows)
-            segs.append((len(tiles) * tile_rows + (start - t0 * tile_rows), cnt))
+            lo, n = start * geom.scale, cnt * geom.scale
+            t0 = lo // tile
+            t1 = -(-(lo + n) // tile)
+            segs.append((len(tiles) * tile + (lo - t0 * tile), n))
             tiles.extend(range(t0, t1))
         result = (np.asarray(tiles, dtype=np.int32), tuple(segs))
         with self._pack_lock:
@@ -503,13 +507,61 @@ def _resolve_pack_geom(plan: CompiledPlan, mode: Optional[str]) -> PackGeometry:
     return plan._resolve_geometry(mode)
 
 
+def pack_tiling(geom: PackGeometry, n_axis: int,
+                dtype: Any) -> Tuple[int, int]:
+    """``(tile, block)`` of the kernel frame for a source holding ``n_axis``
+    indices along the decomposed axis.
+
+    ``tile`` is the gather granule along the decomposed frame dimension
+    (rows in rows mode, flattened columns in cols mode): one sublane group
+    of rows, or in cols mode the narrowest lane-aligned multiple of
+    ``scale`` (so runs, which start on ``scale`` multiples, need no trim),
+    cut to fewer lanes only when even one sublane group of it would
+    overflow a block.  ``block`` is the block length along the other
+    dimension.  Both meet the Mosaic rule -- a multiple of the (sublane,
+    128-lane) granule, or the whole dimension -- and one block stays within
+    ``kernels.pack.BLOCK_BYTES`` whatever the field's width and height.
+    """
+    from repro.kernels import pack
+
+    itemsize = np.dtype(dtype).itemsize
+    sub = pack.sublanes(dtype)
+    budget = pack.BLOCK_BYTES
+    if geom.mode == "rows":
+        tile = min(sub, n_axis)
+        other, other_granule = geom.cols, pack.LANES
+    else:
+        other, other_granule = geom.rows, sub
+        tile = pack.choose_block(math.lcm(geom.scale, pack.LANES), pack.LANES,
+                                 min(sub, other) * itemsize, budget)
+        tile = min(tile, n_axis * geom.scale)
+    return tile, pack.choose_block(other, other_granule, tile * itemsize,
+                                   budget)
+
+
+def _on_one_device(src):
+    """A Mosaic kernel cannot be partitioned: gather a buffer sharded over
+    several devices onto the first device of its own sharding, device to
+    device (the host never holds it).  Traced values pass as they are."""
+    import jax
+
+    concrete = (isinstance(src, jax.Array)
+                and not isinstance(src, jax.core.Tracer))
+    devs = src.sharding.device_set if concrete else ()
+    if len(devs) <= 1:
+        return src
+    return jax.device_put(src, min(devs, key=lambda d: d.id))
+
+
 def _flatten_and_pad(plan: CompiledPlan, src, geom: PackGeometry,
-                     tile_rows: int, slab_box: Optional[Box]):
+                     slab_box: Optional[Box]):
     """Flatten the (slab or global) device buffer onto the 2-D kernel frame
+    of one device (``_on_one_device``), choose the tiling (``pack_tiling``)
     and pad the decomposed axis up to tile granularity (one copy, reused for
-    every dst rank's gather).  Returns ``(src2d, slab_start, slab_extent)``
-    -- the slab's origin and length along the decomposed axis (the global
-    extent when ``slab_box`` is None).
+    every dst rank's gather).
+    Returns ``(src2d, tile, block, slab_start, slab_extent)`` -- the slab's
+    origin and length along the decomposed axis (the global extent when
+    ``slab_box`` is None).
 
     ``slab_box`` declares that ``src`` holds only the slab
     ``(starts, shape)`` of the global index space; the slab must span the
@@ -535,17 +587,19 @@ def _flatten_and_pad(plan: CompiledPlan, src, geom: PackGeometry,
             f"pack source has shape {tuple(src.shape)}, expected "
             f"{expect} (axis {geom.axis} may be pre-padded)")
     # flatten: row-major bytes are already in kernel order (see PackGeometry)
+    src = _on_one_device(src)
     n_axis = int(src.shape[geom.axis])
+    tile, block = pack_tiling(geom, n_axis, src.dtype)
     if geom.mode == "rows":
-        src2d = src.reshape(n_axis, geom.cols)
-        return _pad_to_tiles(src2d, tile_rows, 0), slab_start, slab_extent
-    src2d = src.reshape(geom.rows, n_axis * geom.scale)
-    return (_pad_to_tiles(src2d, tile_rows * geom.scale, 1),
-            slab_start, slab_extent)
+        src2d = _pad_to_tiles(src.reshape(n_axis, geom.cols), tile, 0)
+    else:
+        src2d = _pad_to_tiles(src.reshape(geom.rows, n_axis * geom.scale),
+                              tile, 1)
+    return src2d, tile, block, slab_start, slab_extent
 
 
-def _pack_gather(plan: CompiledPlan, dst_rank: int, src2d,
-                 tile_rows: int, geom: PackGeometry, slab_start: int,
+def _pack_gather(plan: CompiledPlan, dst_rank: int, src2d, tile: int,
+                 block: int, geom: PackGeometry, slab_start: int,
                  slab_extent: Optional[int] = None):
     """Gather one dst rank's block from the flattened+padded 2-D buffer and
     unflatten it back to the N-D destination block shape."""
@@ -554,26 +608,26 @@ def _pack_gather(plan: CompiledPlan, dst_rank: int, src2d,
     from repro.kernels import ops
 
     dshape = plan.dst[dst_rank][1]
-    tiles, segs = plan.pack_tiles(dst_rank, tile_rows, mode=geom.mode,
+    tiles, segs = plan.pack_tiles(dst_rank, tile, mode=geom.mode,
                                   slab_start=slab_start,
                                   slab_extent=slab_extent)
     if tiles.size == 0:
         return jnp.zeros(dshape, dtype=src2d.dtype)
     if geom.mode == "rows":
-        packed = ops.pack_blocks(src2d, jnp.asarray(tiles), tile_rows=tile_rows)
+        packed = ops.pack_blocks(src2d, jnp.asarray(tiles), tile_rows=tile,
+                                 block_cols=block)
         parts = [packed[a : a + c] for a, c in segs]
         out = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
     else:
-        k = geom.scale
-        packed = ops.pack_cols(src2d, jnp.asarray(tiles),
-                               tile_cols=tile_rows * k)
-        parts = [packed[:, a * k : (a + c) * k] for a, c in segs]
+        packed = ops.pack_cols(src2d, jnp.asarray(tiles), tile_cols=tile,
+                               block_rows=block)
+        parts = [packed[:, a : a + c] for a, c in segs]
         out = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
     return out.reshape(dshape)
 
 
 def execute_pack_jax(plan: CompiledPlan, dst_rank: int, src,
-                     tile_rows: int = 8, mode: Optional[str] = None,
+                     mode: Optional[str] = None,
                      slab_box: Optional[Box] = None):
     """Device-resident reshard: gather dst_rank's block with the Pallas pack
     kernels (``kernels.pack`` scalar-prefetch DMA tiles).
@@ -587,21 +641,23 @@ def execute_pack_jax(plan: CompiledPlan, dst_rank: int, src,
 
     ``mode`` picks the tile layout -- ``"rows"`` (``pack_blocks``, axis-0
     decompositions) or ``"cols"`` (``pack_cols``, any other axis); ``None``
-    takes the plan's detected ``pack_mode``.  ``tile_rows`` is the tile
-    extent in decomposed-axis units.  Tile offsets come from the cached plan
-    lowering (``plan.pack_tiles``); ragged run boundaries are padded to tile
-    granularity and trimmed back here.  Gathering several dst ranks from one
-    buffer?  Use ``execute_pack_jax_all`` so the flatten/pad copy happens
-    once, not per rank.  Runs in interpret mode on CPU, Mosaic on TPU.
+    takes the plan's detected ``pack_mode``.  The tiling comes from the
+    buffer's shape and dtype (``pack_tiling``).  Tile offsets come from the
+    cached plan lowering (``plan.pack_tiles``); ragged run boundaries are
+    padded to tile granularity and trimmed back here.  A buffer sharded
+    over several devices is gathered onto one of them first.  Gathering
+    several dst ranks from one buffer?  Use ``execute_pack_jax_all`` so the
+    flatten/pad copy happens once, not per rank.  Runs in interpret mode on
+    CPU, Mosaic on TPU.
     """
     geom = _resolve_pack_geom(plan, mode)
-    src2d, slab_start, slab_extent = _flatten_and_pad(
-        plan, src, geom, tile_rows, slab_box)
-    return _pack_gather(plan, dst_rank, src2d, tile_rows, geom, slab_start,
+    src2d, tile, block, slab_start, slab_extent = _flatten_and_pad(
+        plan, src, geom, slab_box)
+    return _pack_gather(plan, dst_rank, src2d, tile, block, geom, slab_start,
                         slab_extent)
 
 
-def execute_pack_jax_all(plan: CompiledPlan, src, tile_rows: int = 8,
+def execute_pack_jax_all(plan: CompiledPlan, src,
                          mode: Optional[str] = None,
                          slab_box: Optional[Box] = None,
                          ranks: Optional[Sequence[int]] = None):
@@ -613,10 +669,10 @@ def execute_pack_jax_all(plan: CompiledPlan, src, tile_rows: int = 8,
     Returns the block list aligned to ``ranks`` (default: every dst rank).
     """
     geom = _resolve_pack_geom(plan, mode)
-    src2d, slab_start, slab_extent = _flatten_and_pad(
-        plan, src, geom, tile_rows, slab_box)
+    src2d, tile, block, slab_start, slab_extent = _flatten_and_pad(
+        plan, src, geom, slab_box)
     wanted = range(len(plan.dst)) if ranks is None else ranks
-    return [_pack_gather(plan, r, src2d, tile_rows, geom, slab_start,
+    return [_pack_gather(plan, r, src2d, tile, block, geom, slab_start,
                          slab_extent)
             for r in wanted]
 

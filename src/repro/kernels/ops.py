@@ -1,8 +1,9 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode --
-the kernel body runs in Python for correctness validation; on a TPU backend
-they compile to Mosaic.  The wrappers also own layout adaptation (BSHD <->
+On the CPU backend the kernels execute in ``interpret=True`` mode -- the
+kernel body runs in Python for correctness validation; on a TPU backend
+they compile to Mosaic.  Any other backend is an error, not a silent
+interpreter run.  The wrappers also own layout adaptation (BSHD <->
 BHSD transposes, chunking/padding) so model code calls a clean surface.
 """
 
@@ -21,7 +22,14 @@ __all__ = ["flash_attention", "ssd_chunked_pallas", "pack_blocks", "pack_cols"]
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"the Pallas kernels compile for TPU or run interpreted on CPU; "
+        f"JAX's default backend is {backend!r}")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -149,13 +157,18 @@ def _ssd_impl(x, dA, Bm, Cm, chunk: int = 256, initial_state=None):
     return y.astype(x.dtype), final
 
 
-@functools.partial(jax.jit, static_argnames=("tile_rows",))
-def pack_blocks(src, tile_offsets, tile_rows: int = 8):
-    return _pack.pack_blocks(src, tile_offsets, tile_rows=tile_rows,
-                             interpret=_interpret())
+# ``interpret`` is resolved outside the jit so it is part of the cache key.
+_pack_blocks = jax.jit(_pack.pack_blocks,
+                       static_argnames=("tile_rows", "block_cols", "interpret"))
+_pack_cols = jax.jit(_pack.pack_cols,
+                     static_argnames=("tile_cols", "block_rows", "interpret"))
 
 
-@functools.partial(jax.jit, static_argnames=("tile_cols",))
-def pack_cols(src, tile_offsets, tile_cols: int = 8):
-    return _pack.pack_cols(src, tile_offsets, tile_cols=tile_cols,
-                           interpret=_interpret())
+def pack_blocks(src, tile_offsets, tile_rows: int, block_cols: int):
+    return _pack_blocks(src, tile_offsets, tile_rows=tile_rows,
+                        block_cols=block_cols, interpret=_interpret())
+
+
+def pack_cols(src, tile_offsets, tile_cols: int, block_rows: int):
+    return _pack_cols(src, tile_offsets, tile_cols=tile_cols,
+                      block_rows=block_rows, interpret=_interpret())

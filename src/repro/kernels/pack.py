@@ -16,18 +16,48 @@ Two tile layouts cover the planner's 1-D decompositions of a 2-D buffer:
   (rows, tile_cols) and the scalar operand indexes source column-tiles, so
   axis!=0 reshards stay on the kernel path instead of falling back to numpy.
 
-The planner pads ragged blocks up to tile granularity (LowFive ships whole
-hyperslabs, same idea).
+A second grid axis walks the other dimension in blocks of ``block_cols`` /
+``block_rows``, so a block's bytes do not grow with the field: a whole
+(8, 262144) f32 row tile is 8 MiB and overflows the v5e's scoped VMEM once
+in, out and their double buffers are counted.  ``choose_block`` and
+``sublanes`` give the sizes the Mosaic compiler accepts; ``BLOCK_BYTES``
+bounds each block.  The planner pads ragged blocks up to tile granularity
+(LowFive ships whole hyperslabs, same idea).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+# One block's bytes: in + out, each double-buffered, is 4 blocks = 8 MiB,
+# half of the v5e's 16 MiB default scoped VMEM (a 4 MiB block compiled
+# there, an 8 MiB one ran out of VMEM).
+BLOCK_BYTES = 2 << 20
+
+
+def sublanes(dtype) -> int:
+    """Row granule of a block: 8 sublanes of 32 bits, more rows when packed."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def choose_block(extent: int, granule: int, unit_bytes: int,
+                 budget: int) -> int:
+    """Block length along a dimension of ``extent`` indices, each moving
+    ``unit_bytes``: the whole dimension when it fits ``budget`` or is no
+    longer than ``granule``, else the largest multiple of ``granule`` that
+    fits and divides ``extent``, else that multiple with a ragged last
+    block."""
+    if extent * unit_bytes <= budget or extent <= granule:
+        return extent
+    cap = max(granule, budget // unit_bytes // granule * granule)
+    for b in range(cap, 0, -granule):
+        if extent % b == 0:
+            return b
+    return cap
 
 
 def _pack_kernel(offs_ref, src_ref, out_ref):
@@ -37,13 +67,15 @@ def _pack_kernel(offs_ref, src_ref, out_ref):
 def pack_blocks(
     src: jnp.ndarray,          # (R, C) source buffer
     tile_offsets: jnp.ndarray,  # (T,) int32: source row-tile index per out tile
-    tile_rows: int = 8,
+    tile_rows: int,
+    block_cols: int,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Gather T row-tiles of ``tile_rows`` rows each into a contiguous buffer.
 
     out[t*tile_rows:(t+1)*tile_rows] = src[tile_offsets[t]*tile_rows : ...]
 
+    Each tile moves in ``block_cols``-wide blocks.
     A ragged source (rows not a multiple of ``tile_rows``) is zero-padded up
     to tile granularity so the last tile's DMA stays in bounds; callers that
     gather the tail tile (the redistribution pack executor) trim the pad rows
@@ -57,11 +89,13 @@ def pack_blocks(
     t = tile_offsets.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(t,),
+        grid=(t, pl.cdiv(c, block_cols)),
         in_specs=[
-            pl.BlockSpec((tile_rows, c), lambda i, offs: (offs[i], 0)),
+            pl.BlockSpec((tile_rows, block_cols),
+                         lambda i, j, offs: (offs[i], j)),
         ],
-        out_specs=pl.BlockSpec((tile_rows, c), lambda i, offs: (i, 0)),
+        out_specs=pl.BlockSpec((tile_rows, block_cols),
+                               lambda i, j, offs: (i, j)),
     )
     return pl.pallas_call(
         _pack_kernel,
@@ -74,7 +108,8 @@ def pack_blocks(
 def pack_cols(
     src: jnp.ndarray,           # (R, C) source buffer
     tile_offsets: jnp.ndarray,  # (T,) int32: source col-tile index per out tile
-    tile_cols: int = 8,
+    tile_cols: int,
+    block_rows: int,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Gather T column-tiles of ``tile_cols`` columns each, contiguously.
@@ -83,10 +118,11 @@ def pack_cols(
 
     The column twin of ``pack_blocks``: the grid walks output column tiles
     and the scalar-prefetch operand points each tile's DMA at the right
-    source column band (full-height (R, tile_cols) blocks).  A ragged source
+    source column band, ``block_rows`` rows at a time.  A ragged source
     (columns not a multiple of ``tile_cols``) is zero-padded up to tile
     granularity; callers trim the pad columns back off the packed output.
-    On real TPU prefer ``tile_cols`` multiples of the 128-lane width.
+    On TPU ``tile_cols`` must be a multiple of the 128-lane width or the
+    whole (padded) width.
     """
     r, c = src.shape
     pad = -c % tile_cols
@@ -96,11 +132,13 @@ def pack_cols(
     t = tile_offsets.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(t,),
+        grid=(t, pl.cdiv(r, block_rows)),
         in_specs=[
-            pl.BlockSpec((r, tile_cols), lambda i, offs: (0, offs[i])),
+            pl.BlockSpec((block_rows, tile_cols),
+                         lambda i, j, offs: (j, offs[i])),
         ],
-        out_specs=pl.BlockSpec((r, tile_cols), lambda i, offs: (0, i)),
+        out_specs=pl.BlockSpec((block_rows, tile_cols),
+                               lambda i, j, offs: (j, i)),
     )
     return pl.pallas_call(
         _pack_kernel,

@@ -157,22 +157,11 @@ def moe_a2a(p: Params, cfg, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
             aux = jax.lax.pmean(aux, tuple(axes))
         return y.reshape(bl, sl, d), aux
 
-    if hasattr(jax, "shard_map"):  # jax >= 0.6: top-level API, check_vma kwarg
-        fn = jax.shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(x_spec, P(None, None), gate_spec, gate_spec, down_spec),
-            out_specs=(x_spec, P()),
-            check_vma=False,
-        )
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        fn = _shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(x_spec, P(None, None), gate_spec, gate_spec, down_spec),
-            out_specs=(x_spec, P()),
-            check_rep=False,
-        )
+    fn = jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(x_spec, P(None, None), gate_spec, gate_spec, down_spec),
+        out_specs=(x_spec, P()),
+        check_vma=False,
+    )
     return fn(x, p["router"], p["gate"], p["up"], p["down"])

@@ -81,6 +81,7 @@ SCRIPT = textwrap.dedent("""
 def test_checkpoint_restores_across_mesh_shapes():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"  # virtual host devices, never the chip
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]

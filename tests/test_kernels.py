@@ -120,7 +120,7 @@ def test_pack_blocks_property(t, rows, cols, seed):
     n_tiles_src = 16
     src = jnp.asarray(rng.normal(size=(n_tiles_src * rows, cols)), jnp.float32)
     offs = jnp.asarray(rng.integers(0, n_tiles_src, size=t), jnp.int32)
-    got = ops.pack_blocks(src, offs, tile_rows=rows)
+    got = ops.pack_blocks(src, offs, tile_rows=rows, block_cols=cols)
     want = ref.pack_blocks_ref(src, offs, tile_rows=rows)
     np.testing.assert_array_equal(np.asarray(got), want)
 
@@ -129,7 +129,7 @@ def test_pack_blocks_property(t, rows, cols, seed):
 def test_pack_blocks_dtypes(dtype):
     src = jnp.arange(64 * 8).reshape(64, 8).astype(dtype)
     offs = jnp.asarray([7, 0, 3], jnp.int32)
-    got = ops.pack_blocks(src, offs, tile_rows=8)
+    got = ops.pack_blocks(src, offs, tile_rows=8, block_cols=8)
     want = ref.pack_blocks_ref(src, offs, tile_rows=8)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -146,7 +146,7 @@ def test_pack_cols_property(t, rows, cols, seed):
     n_tiles_src = 16
     src = jnp.asarray(rng.normal(size=(rows, n_tiles_src * cols)), jnp.float32)
     offs = jnp.asarray(rng.integers(0, n_tiles_src, size=t), jnp.int32)
-    got = ops.pack_cols(src, offs, tile_cols=cols)
+    got = ops.pack_cols(src, offs, tile_cols=cols, block_rows=rows)
     want = ref.pack_cols_ref(src, offs, tile_cols=cols)
     np.testing.assert_array_equal(np.asarray(got), want)
 
@@ -155,6 +155,90 @@ def test_pack_cols_property(t, rows, cols, seed):
 def test_pack_cols_dtypes(dtype):
     src = jnp.arange(8 * 64).reshape(8, 64).astype(dtype)
     offs = jnp.asarray([7, 0, 3], jnp.int32)
-    got = ops.pack_cols(src, offs, tile_cols=8)
+    got = ops.pack_cols(src, offs, tile_cols=8, block_rows=8)
     want = ref.pack_cols_ref(src, offs, tile_cols=8)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("cols, block_cols", [
+    (512, 128),    # the second grid axis divides the width
+    (400, 128),    # ragged last column block
+])
+def test_pack_blocks_second_grid_axis(cols, block_cols):
+    src = jnp.asarray(RNG.normal(size=(64, cols)), jnp.float32)
+    offs = jnp.asarray([7, 0, 3, 7], jnp.int32)
+    got = ops.pack_blocks(src, offs, tile_rows=8, block_cols=block_cols)
+    want = ref.pack_blocks_ref(src, offs, tile_rows=8)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("rows, block_rows", [
+    (64, 16),      # the second grid axis divides the height
+    (60, 16),      # ragged last row block
+])
+def test_pack_cols_second_grid_axis(rows, block_rows):
+    src = jnp.asarray(RNG.normal(size=(rows, 8 * 128)), jnp.float32)
+    offs = jnp.asarray([5, 0, 2], jnp.int32)
+    got = ops.pack_cols(src, offs, tile_cols=128, block_rows=block_rows)
+    want = ref.pack_cols_ref(src, offs, tile_cols=128)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("shape, axis, dtype, want", [
+    # 512^3 f32 along axis 0: 8-row tiles, 64 Ki-column blocks (2 MiB)
+    ((512, 512, 512), 0, np.float32, (8, 65536)),
+    # along axis 1: one 512-column tile per index, full 512-row height
+    ((512, 512, 512), 1, np.float32, (512, 512)),
+    # 2-D along axis 1: 128-lane tiles, the height cut to 2 MiB blocks
+    ((16384, 16384), 1, np.float32, (128, 4096)),
+    # packed dtypes take more rows per sublane group
+    ((4096, 4096), 0, jnp.bfloat16, (16, 4096)),
+    # small frames: a tile covering the whole axis is legal
+    ((6, 40, 3), 1, np.float32, (120, 6)),
+    ((5, 7), 0, np.float32, (5, 7)),
+])
+def test_pack_tiling_is_legal_and_bounded(shape, axis, dtype, want):
+    from repro.core.redistribute import CompiledPlan, even_blocks, pack_tiling
+    from repro.kernels.pack import BLOCK_BYTES, LANES, sublanes
+
+    plan = CompiledPlan([((0,) * len(shape), shape)],
+                        even_blocks(shape, 4, axis=axis), shape, dtype)
+    geom = plan.pack_geometry
+    tile, block = pack_tiling(geom, shape[axis], dtype)
+    assert (tile, block) == want
+    itemsize = np.dtype(dtype).itemsize
+    assert tile * block * itemsize <= BLOCK_BYTES
+    if geom.mode == "rows":
+        extent, other = shape[0], geom.cols
+        assert tile % sublanes(dtype) == 0 or tile == extent
+        assert block % LANES == 0 or block == other
+    else:
+        extent, other = shape[axis] * geom.scale, geom.rows
+        assert tile % LANES == 0 or tile == extent
+        assert block % sublanes(dtype) == 0 or block == other
+
+
+def test_pack_tiling_cuts_lane_tiles_to_the_block_budget(monkeypatch):
+    from repro.core.redistribute import CompiledPlan, even_blocks, pack_tiling
+    from repro.kernels import pack
+
+    # (16, 17, 4, 5) along axis 1: runs of 20 columns, lcm(20, 128) = 640
+    shape = (16, 17, 4, 5)
+    plan = CompiledPlan([((0,) * 4, shape)], even_blocks(shape, 3, axis=1),
+                        shape, np.float32)
+    geom = plan.pack_geometry
+    assert pack_tiling(geom, 17, np.float32) == (340, 16)  # the whole frame
+    # one sublane group of 640 lanes no longer fits: 128-lane tiles, off
+    # the runs, and the 16 rows move in two blocks of 8
+    monkeypatch.setattr(pack, "BLOCK_BYTES", 8 * 128 * 4)
+    assert pack_tiling(geom, 17, np.float32) == (128, 8)
+
+
+def test_interpret_only_on_cpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops._interpret()

@@ -84,6 +84,7 @@ MULTIDEV = textwrap.dedent("""
 def test_a2a_matches_oracle_on_8_virtual_devices():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"  # virtual host devices, never the chip
     out = subprocess.run([sys.executable, "-c", MULTIDEV], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]

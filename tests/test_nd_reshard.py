@@ -30,17 +30,25 @@ def _ref(g, src, dst):
     return redistribute_numpy(g, src, dst)
 
 
-@pytest.mark.parametrize("shape, axis, m_src, m_dst, tile", [
-    ((37, 5, 6), 0, 4, 3, 4),    # 3-D rows lowering (ragged axis extent)
-    ((6, 40, 3), 1, 3, 2, 4),    # 3-D middle axis -> flattened cols, scale>1
-    ((4, 6, 23), 2, 4, 5, 4),    # 3-D last axis
-    ((3, 4, 5, 23), 3, 4, 5, 4),  # 4-D last axis
-    ((23, 3, 4, 5), 0, 5, 2, 8),  # 4-D rows
-    ((3, 17, 4, 5), 1, 2, 3, 4),  # 4-D middle axis
-])
-def test_nd_pack_matches_numpy_reference(shape, axis, m_src, m_dst, tile):
+# block_bytes: None keeps kernels.pack.BLOCK_BYTES; a smaller budget makes
+# pack_tiling's own choice cut the frame into several blocks or tiles
+@pytest.mark.parametrize("shape, axis, m_src, m_dst, block_bytes", [
+    ((37, 5, 6), 0, 4, 3, None),    # 3-D rows lowering (ragged axis extent)
+    ((6, 40, 3), 1, 3, 2, None),  # 3-D middle axis -> flattened cols, scale>1
+    ((4, 6, 23), 2, 4, 5, None),  # 3-D last axis
+    ((3, 4, 5, 23), 3, 4, 5, 736),  # 4-D last axis, ragged 8-row blocks
+    ((23, 3, 4, 5), 0, 5, 2, None),  # 4-D rows
+    ((3, 17, 4, 5), 1, 2, 3, 1536),  # 4-D middle axis, tiles off scale runs
+], ids=["shape0-0-4-3-4", "shape1-1-3-2-4", "shape2-2-4-5-4",
+        "shape3-3-4-5-4", "shape4-0-5-2-8", "shape5-1-2-3-4"])
+def test_nd_pack_matches_numpy_reference(shape, axis, m_src, m_dst,
+                                         block_bytes, monkeypatch):
     import jax.numpy as jnp
 
+    from repro.kernels import pack
+
+    if block_bytes is not None:
+        monkeypatch.setattr(pack, "BLOCK_BYTES", block_bytes)
     rng = np.random.default_rng(hash(shape) % 2**31)
     g = rng.normal(size=shape).astype(np.float32)
     src = even_blocks(shape, m_src, axis=axis)
@@ -49,12 +57,12 @@ def test_nd_pack_matches_numpy_reference(shape, axis, m_src, m_dst, tile):
     assert plan.pack_mode == ("rows" if axis == 0 else "cols")
     assert plan.pack_axis == axis
     want = _ref(g, src, dst)
-    got = execute_pack_jax_all(plan, jnp.asarray(g), tile_rows=tile)
+    got = execute_pack_jax_all(plan, jnp.asarray(g))
     assert len(got) == m_dst
     for w, a in zip(want, got):
         np.testing.assert_array_equal(w, np.asarray(a))
     # single-rank entry point agrees
-    one = execute_pack_jax(plan, m_dst - 1, jnp.asarray(g), tile_rows=tile)
+    one = execute_pack_jax(plan, m_dst - 1, jnp.asarray(g))
     np.testing.assert_array_equal(want[-1], np.asarray(one))
 
 
@@ -68,7 +76,7 @@ def test_nd_cross_axis_exchange_lowers_via_dst_axis():
                         even_blocks(g.shape, 3, axis=2), g.shape, g.dtype)
     assert plan.pack_mode == "cols" and plan.pack_axis == 2
     want = plan.execute_global(g)
-    got = execute_pack_jax_all(plan, jnp.asarray(g), tile_rows=4)
+    got = execute_pack_jax_all(plan, jnp.asarray(g))
     for w, a in zip(want, got):
         np.testing.assert_array_equal(w, np.asarray(a))
 
@@ -106,7 +114,7 @@ def test_reshard_rank3_device_array_takes_pack_path():
     reset_plan_cache()
     reset_transport_stats()
     got = TaskComm().reshard(jnp.asarray(g), spec, ranks="all",
-                             prefer="pack", tile_rows=4)
+                             prefer="pack")
     assert all(isinstance(b, jax.Array) for b in got)
     for w, a in zip(want, got):
         np.testing.assert_array_equal(w, np.asarray(a))
@@ -123,7 +131,7 @@ def test_reshard_rank3_middle_axis_device_array():
     spec = RedistSpec(axis=1, nslots=2, slot=1, nranks=2)
     dst, _ = spec.dst_boxes(g.shape)
     want = redistribute_numpy(g, [((0, 0, 0), g.shape)], dst)
-    got = TaskComm().reshard(jnp.asarray(g), spec, prefer="pack", tile_rows=4)
+    got = TaskComm().reshard(jnp.asarray(g), spec, prefer="pack")
     for r, a in zip(spec.my_ranks(), got):
         np.testing.assert_array_equal(want[r], np.asarray(a))
 
@@ -155,7 +163,7 @@ def test_device_slab_dataset_dispatches_to_pack_kernels():
     ds, dst = _slab_dataset(g, spec, 1, jnp.asarray)
     assert is_device_array(ds.read_direct())
     want = redistribute_numpy(g, [((0, 0, 0), g.shape)], dst)
-    blocks = TaskComm().reshard(ds, spec, prefer="pack", tile_rows=4)
+    blocks = TaskComm().reshard(ds, spec, prefer="pack")
     assert all(isinstance(b, jax.Array) for b in blocks)
     for r, b in zip(spec.my_ranks(), blocks):
         np.testing.assert_array_equal(want[r], np.asarray(b))
@@ -171,7 +179,7 @@ def test_device_slab_2d_axis1_pack_dispatch():
     spec = RedistSpec(axis=1, nslots=2, slot=0, nranks=2)
     ds, dst = _slab_dataset(g, spec, 0, jnp.asarray)
     want = redistribute_numpy(g, [((0, 0), g.shape)], dst)
-    blocks = TaskComm().reshard(ds, spec, prefer="pack", tile_rows=4)
+    blocks = TaskComm().reshard(ds, spec, prefer="pack")
     for r, b in zip(spec.my_ranks(), blocks):
         np.testing.assert_array_equal(want[r], np.asarray(b))
 
@@ -188,7 +196,7 @@ def test_slab_covering_only_run_head_raises_not_corrupts():
     slab_box = ((40, 0), (10, 8))    # but the slab holds rows 40-49 only
     slab = jnp.zeros((10, 8), jnp.float32)
     with pytest.raises(ValueError, match="does not cover this rank"):
-        execute_pack_jax(plan, 0, slab, tile_rows=8, slab_box=slab_box)
+        execute_pack_jax(plan, 0, slab, slab_box=slab_box)
 
 
 def test_host_slab_dataset_still_uses_numpy_scatter():

@@ -264,13 +264,19 @@ def test_redistribute_cached_matches_uncached():
 # ---------------------------------------------------------------------------
 # JAX pack executor (kernels/pack.py lowering)
 # ---------------------------------------------------------------------------
-def test_pack_executor_matches_numpy_scatter():
+def test_pack_executor_matches_numpy_scatter(monkeypatch):
     import jax.numpy as jnp
 
+    from repro.kernels import pack
+
     rng = np.random.default_rng(3)
-    for rows, cols, m_src, m_dst, tile_rows in [
-        (64, 8, 4, 2, 8), (40, 16, 3, 3, 8), (37, 8, 2, 5, 4)
+    default = pack.BLOCK_BYTES
+    # a 4 KiB block budget cuts 300 columns into ragged 128-column blocks
+    for rows, cols, m_src, m_dst, budget in [
+        (64, 8, 4, 2, default), (40, 16, 3, 3, default),
+        (37, 8, 2, 5, default), (37, 300, 2, 5, 4096),
     ]:
+        monkeypatch.setattr(pack, "BLOCK_BYTES", budget)
         g = rng.normal(size=(rows, cols)).astype(np.float32)
         src = even_blocks(g.shape, m_src)
         dst = even_blocks(g.shape, m_dst)
@@ -278,7 +284,7 @@ def test_pack_executor_matches_numpy_scatter():
         want = plan.execute_global(g)
         gj = jnp.asarray(g)
         for r in range(m_dst):
-            got = np.asarray(execute_pack_jax(plan, r, gj, tile_rows=tile_rows))
+            got = np.asarray(execute_pack_jax(plan, r, gj))
             np.testing.assert_array_equal(got, want[r])
 
 
@@ -491,7 +497,7 @@ def test_pack_all_pads_once_and_matches_per_rank():
     plan = CompiledPlan(even_blocks(g.shape, 3), even_blocks(g.shape, 4),
                         g.shape, g.dtype)
     want = plan.execute_global(g)
-    got = execute_pack_jax_all(plan, jnp.asarray(g), tile_rows=8)
+    got = execute_pack_jax_all(plan, jnp.asarray(g))
     assert len(got) == 4
     for w, a in zip(want, got):
         np.testing.assert_array_equal(w, np.asarray(a))
@@ -559,13 +565,20 @@ def test_pack_mode_detection():
     assert oned.pack_mode is None
 
 
-def test_pack_executor_cols_matches_numpy_scatter():
+def test_pack_executor_cols_matches_numpy_scatter(monkeypatch):
     import jax.numpy as jnp
 
+    from repro.kernels import pack
+
     rng = np.random.default_rng(11)
-    for rows, cols, m_src, m_dst, tile in [
-        (8, 64, 4, 2, 8), (16, 40, 3, 3, 8), (8, 37, 2, 5, 4)
+    default = pack.BLOCK_BYTES
+    # 481 columns take four 128-lane tiles and a ragged one; a 4 KiB block
+    # budget moves 40 rows in five blocks of 8
+    for rows, cols, m_src, m_dst, budget in [
+        (8, 64, 4, 2, default), (16, 40, 3, 3, default),
+        (8, 481, 2, 5, default), (40, 300, 3, 4, 4096),
     ]:
+        monkeypatch.setattr(pack, "BLOCK_BYTES", budget)
         g = rng.normal(size=(rows, cols)).astype(np.float32)
         src = even_blocks(g.shape, m_src, axis=1)
         dst = even_blocks(g.shape, m_dst, axis=1)
@@ -574,9 +587,9 @@ def test_pack_executor_cols_matches_numpy_scatter():
         want = plan.execute_global(g)
         gj = jnp.asarray(g)
         for r in range(m_dst):
-            got = np.asarray(execute_pack_jax(plan, r, gj, tile_rows=tile))
+            got = np.asarray(execute_pack_jax(plan, r, gj))
             np.testing.assert_array_equal(got, want[r])
-        allr = execute_pack_jax_all(plan, jnp.asarray(g), tile_rows=tile)
+        allr = execute_pack_jax_all(plan, jnp.asarray(g))
         for w, a in zip(want, allr):
             np.testing.assert_array_equal(w, np.asarray(a))
 
@@ -600,7 +613,7 @@ def test_pack_executor_cross_axis_exchange():
     plan = CompiledPlan(even_blocks(g.shape, 4, axis=0),
                         even_blocks(g.shape, 3, axis=1), g.shape, g.dtype)
     want = plan.execute_global(g)
-    got = execute_pack_jax_all(plan, jnp.asarray(g), tile_rows=4)
+    got = execute_pack_jax_all(plan, jnp.asarray(g))
     for w, a in zip(want, got):
         np.testing.assert_array_equal(w, np.asarray(a))
 

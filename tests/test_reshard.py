@@ -1,5 +1,9 @@
 """TaskComm.reshard -- the one-call user face of the M->N subsystem."""
 
+import os
+import subprocess
+import sys
+import textwrap
 import threading
 
 import numpy as np
@@ -83,12 +87,53 @@ def test_reshard_4to2_axis1_device_pack_path():
     dst, _ = spec.dst_boxes(g.shape)
     want = redistribute_numpy(g, src, dst)
     got = TaskComm().reshard(jnp.asarray(g), spec, src=src, ranks="all",
-                             prefer="pack", tile_rows=4)
+                             prefer="pack")
     assert all(isinstance(b, jax.Array) for b in got)
     for w, a in zip(want, got):
         np.testing.assert_array_equal(w, np.asarray(a))
     plan = plan_cache().get(src, dst, g.shape, g.dtype)
     assert plan.pack_mode == "cols"
+
+
+SHARDED = textwrap.dedent("""
+    import jax, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.comm import TaskComm
+    from repro.core.datamodel import reset_transport_stats, transport_stats
+    from repro.core.redistribute import RedistSpec, redistribute_numpy
+
+    comm = TaskComm(devices=jax.devices())
+    g = np.arange(32 * 256, dtype=np.float32).reshape(32, 256)
+    x = jax.device_put(g, NamedSharding(comm.mesh(), P("data")))
+    assert len(x.sharding.device_set) == 2
+    for axis in (0, 1):
+        spec = RedistSpec(axis=axis, nslots=1, slot=0, nranks=3)
+        reset_transport_stats()
+        got = comm.reshard(x, spec, ranks="all")      # prefer="auto"
+        s = transport_stats().snapshot()
+        assert (s["reshard_pack"], s["reshard_numpy"]) == (1, 0), s
+        dst, _ = spec.dst_boxes(g.shape)
+        want = redistribute_numpy(g, [((0, 0), g.shape)], dst)
+        for w, a in zip(want, got):
+            assert isinstance(a, jax.Array)
+            assert a.devices() <= x.sharding.device_set
+            np.testing.assert_array_equal(w, np.asarray(a))
+    print("SHARDED_OK")
+""")
+
+
+def test_reshard_sharded_device_array_takes_pack_path():
+    """A field sharded over two devices reshards on the kernels under
+    prefer="auto" (gathered onto one of its own devices), never by a
+    silent host round trip through the numpy scatter."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    out = subprocess.run([sys.executable, "-c", SHARDED], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "SHARDED_OK" in out.stdout
 
 
 def test_reshard_device_rows_pack_path():

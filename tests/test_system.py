@@ -433,3 +433,38 @@ tasks:
         kinds.add(type(e))
         e = e.__context__
     assert RuntimeError in kinds
+
+
+NYX_REEBER_YAML = """
+tasks:
+  - func: nyx
+    nprocs: 1024
+    outports:
+      - filename: plt*.h5
+        dsets: [{name: /level_0/density, memory: 1}]
+  - func: reeber
+    nprocs: 64
+    taskCount: 2
+    inports:
+      - filename: plt*.h5
+        dsets: [{name: /level_0/density, memory: 1}]
+"""
+
+
+@pytest.mark.parametrize("n_dev, want", [
+    (4, {("nyx", 0): [0, 1], ("reeber", 0): [2], ("reeber", 1): [3]}),
+    (8, {("nyx", 0): [0, 1, 2, 3, 4, 5], ("reeber", 0): [6],
+         ("reeber", 1): [7]}),
+    # fewer devices than instances: disjoint is impossible, the instances
+    # past the last device share it
+    (2, {("nyx", 0): [0], ("reeber", 0): [1], ("reeber", 1): [1]}),
+])
+def test_device_groups_are_disjoint_proportional_slices(n_dev, want):
+    funcs = {"nyx": lambda: None, "reeber": lambda: None}
+    devices = [f"d{i}" for i in range(n_dev)]
+    w = Wilkins(NYX_REEBER_YAML, funcs, devices=devices)
+    got = {k: [devices.index(d) for d in g] for k, g in w.device_groups.items()}
+    assert got == want
+    if n_dev >= len(want):
+        flat = [d for g in got.values() for d in g]
+        assert sorted(flat) == list(range(n_dev))  # disjoint and complete
