@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core import Wilkins, h5
 from repro.obs import span_categories
+from repro.obs.critical import PRECEDENCE
 from repro.obs.recorder import created_count
 
 from .common import Timer, emit, write_json
@@ -121,9 +122,7 @@ def main(smoke: bool = False) -> Dict[str, Any]:
     att_nonempty = bool(att.get("instances")) and bool(att.get("edges"))
     att_sums_ok = att_nonempty
     for key, row in att.get("instances", {}).items():
-        total = sum(row[b] for b in ("block", "prep", "reshard",
-                                     "checkpoint", "recovery", "rescale",
-                                     "compute"))
+        total = sum(row[b] for b in PRECEDENCE + ("compute",))
         if abs(total - row["window_s"]) > 0.05 * max(row["window_s"], 1e-9):
             att_sums_ok = False
     # layer coverage: a dedicated short traced run with an exported trace

@@ -49,7 +49,7 @@ import numpy as np
 
 from ..analysis.lockcheck import (check_blocking, hb_consume, hb_publish,
                                   make_condition, make_lock, sched_point)
-from ..obs.recorder import flow_id
+from ..obs.recorder import NO_ANNOTATION, flow_id
 from .datamodel import (BlockOwnership, File, compile_file_pattern,
                         compile_path_pattern, transport_stats)
 from .redistribute import RedistSpec, plan_cache
@@ -1052,7 +1052,8 @@ class Channel:
             try:
                 pool = self._prefetch_pool or _prefetch_pool()
                 fut = pool.submit(self._prepare_timed, f, _payload_cache,
-                                  step, edge=self.name, weight=self.weight)
+                                  step, seq, edge=self.name,
+                                  weight=self.weight)
             except BaseException:
                 self._prefetch_sem.release()
                 raise
@@ -1069,8 +1070,10 @@ class Channel:
         else:
             payload, payload_bytes = self._prepare(f, _payload_cache)
             item = (payload[0], payload[1], seq, epoch, None)
+        tr = self._tracer
         t0 = time.monotonic()
-        with self._lock:
+        with NO_ANNOTATION if tr is None else tr.annotate("channel.offer"), \
+                self._lock:
             if self.strategy == FlowControl.LATEST and depth:
                 # a newer step supersedes any queued payload future whose
                 # prep has not finished: cancel it rather than prepare
@@ -1090,7 +1093,6 @@ class Channel:
             now = time.monotonic()
             self.stats.producer_wait_s += now - t0
             self._event_locked("producer", "wait_end")
-            tr = self._tracer
             if self._abandoned:
                 if tr is not None:
                     tr.record("channel", "channel.offer", self.producer[0],
@@ -1152,7 +1154,8 @@ class Channel:
         return dropped
 
     def _prepare_timed(
-        self, f: File, cache: Optional[Dict[Any, File]] = None, step: int = 0
+        self, f: File, cache: Optional[Dict[Any, File]] = None, step: int = 0,
+        seq: int = 0,
     ) -> Tuple[Tuple[str, Any], int]:
         """``_prepare`` on the prefetch executor, timed for the overlap
         accounting (prepared vs consumer-blocked seconds).
@@ -1165,19 +1168,23 @@ class Channel:
         sup = self._supervisor
         if sup is not None:
             sup.fire(self.producer[0], self.producer[1], "prefetch", step)
+        tr = self._tracer
         t0 = time.monotonic()
-        item, payload_bytes = self._prepare(f, cache)
+        if tr is None:
+            item, payload_bytes = self._prepare(f, cache)
+        else:
+            # pool workers get their own pseudo-process track: overlapping
+            # preps must not stack onto a task instance's timeline
+            with tr.span("prefetch", "prefetch.prep", "pool",
+                         threading.get_ident() & 0xF, step=step,
+                         flow=("t", flow_id(self.name, seq)),
+                         edge=self.name) as args:
+                item, payload_bytes = self._prepare(f, cache)
+                args["bytes"] = payload_bytes
         dt = time.monotonic() - t0
         transport_stats().record_prefetch_prepare(dt)
         with self._lock:
             self.stats.prefetch_prepared_s += dt
-        tr = self._tracer
-        if tr is not None:
-            # pool workers get their own pseudo-process track: overlapping
-            # preps must not stack onto a task instance's timeline
-            tr.record("prefetch", "prefetch.prep", "pool",
-                      threading.get_ident() & 0xF, t0, t0 + dt, step=step,
-                      edge=self.name, bytes=payload_bytes)
         return item, payload_bytes
 
     def _prepare(
@@ -1275,14 +1282,21 @@ class Channel:
         self._lock.notify_all()
         return item
 
-    def _deliver(self, item: Tuple[str, Any, int, int, Any]) -> File:
+    def _deliver(self, item: Tuple[str, Any, int, int, Any],
+                 step: Optional[int] = None) -> File:
+        """Hand ``item`` to the consumer, waiting for its prep if it is a
+        payload future; ``step`` is the consumer's, for the trace."""
         kind, payload, seq, epoch, src = item
         if kind == "future":
             fut: "Future[Tuple[Tuple[str, Any], int]]" = payload
             hit = fut.done()
+            tr = self._tracer
             t0 = time.monotonic()
             try:
-                inner, payload_bytes = fut.result()  # re-raises prepare errors
+                with NO_ANNOTATION if tr is None else tr.annotate(
+                        "prefetch.wait"):
+                    # re-raises prepare errors
+                    inner, payload_bytes = fut.result()
                 fail = None
             except BaseException as e:
                 fut._wilkins_observed = True  # consumer saw it: not "dropped"
@@ -1319,13 +1333,13 @@ class Channel:
                 else:
                     self.stats.prefetch_misses += 1
                     self.stats.prefetch_blocked_s += blocked
-            tr = self._tracer
             if tr is not None:
                 # zero-length on a hit: still carries the cache verdict and
                 # the payload bytes for the per-edge rollup
                 tr.record("prefetch", "prefetch.wait", self.consumer[0],
-                          self.consumer[1], t0, t0 + blocked, edge=self.name,
-                          cache="hit" if hit else "miss",
+                          self.consumer[1], t0, t0 + blocked, step=step,
+                          flow=("t", flow_id(self.name, seq)),
+                          edge=self.name, cache="hit" if hit else "miss",
                           bytes=payload_bytes)
             kind, payload = inner
         if kind == "file":
@@ -1346,7 +1360,8 @@ class Channel:
                 self._replay.append(("memory", f.view(), seq, epoch, None))
         return f
 
-    def get(self, timeout: Optional[float] = None) -> Optional[File]:
+    def get(self, timeout: Optional[float] = None,
+            step: Optional[int] = None) -> Optional[File]:
         """Consumer-side blocking receive.
 
         Returns the next ``File``; ``None`` means the producer is all-done
@@ -1356,13 +1371,15 @@ class Channel:
         (the driver poisoned the channel), raises ``ChannelError`` naming
         the dead task immediately -- a blocked consumer is woken, it does
         not wait out its timeout.  Data queued before the failure still
-        delivers first.
+        delivers first.  ``step`` is the consumer's, for the trace.
         """
         check_blocking("Channel.get")
         sched_point("Channel.get", key=("chan", id(self)))
+        tr = self._tracer
         t0 = time.monotonic()
         deadline = None if timeout is None else t0 + timeout
-        with self._lock:
+        with NO_ANNOTATION if tr is None else tr.annotate("channel.get"), \
+                self._lock:
             if self._interrupt is not None:
                 raise self._interrupt
             self._waiter_enter_locked()
@@ -1374,8 +1391,8 @@ class Channel:
                         if not _in_mux_wait_scope(self):
                             self.stats.consumer_wait_s += time.monotonic() - t0
                         self._event_locked("consumer", "timeout")
-                        if self._tracer is not None:
-                            self._tracer.record(
+                        if tr is not None:
+                            tr.record(
                                 "channel", "channel.get", self.consumer[0],
                                 self.consumer[1], t0, time.monotonic(),
                                 edge=self.name, aborted=True, why="timeout")
@@ -1392,7 +1409,6 @@ class Channel:
                 now = time.monotonic()
                 if not _in_mux_wait_scope(self):
                     self.stats.consumer_wait_s += now - t0
-                tr = self._tracer
                 if self._interrupt is not None:
                     if tr is not None:
                         tr.record("channel", "channel.get", self.consumer[0],
@@ -1418,7 +1434,7 @@ class Channel:
                     return None  # all done
             finally:
                 self._waiter_exit_locked()
-        return self._deliver(item)
+        return self._deliver(item, step)
 
     def _poison_error_locked(self) -> ChannelError:
         """Build the poison-pill exception (caller holds the lock, and
@@ -1432,7 +1448,7 @@ class Channel:
         err.__cause__ = cause
         return err
 
-    def try_get(self) -> Any:
+    def try_get(self, step: Optional[int] = None) -> Any:
         """Non-blocking receive: a ``File``, ``None`` (producer all-done), or
         ``NO_DATA`` (queue empty, producer still live).  Raises
         ``ChannelError`` if the producer failed permanently (poison pill --
@@ -1448,7 +1464,7 @@ class Channel:
                 return None
             else:
                 return NO_DATA
-        return self._deliver(item)
+        return self._deliver(item, step)
 
     def set_consumer_waiting(self, waiting: bool) -> None:
         """Mark the consumer as blocked on this channel (used by the VOL

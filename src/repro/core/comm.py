@@ -30,7 +30,6 @@ from the process-wide ``PlanCache``.
 from __future__ import annotations
 
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -155,12 +154,11 @@ class TaskComm:
         if self.tracer is None:
             return self.recovery.checkpoint(state, step=step, block=block,
                                             sharded_axes=sharded_axes)
-        t0 = time.monotonic()
-        out = self.recovery.checkpoint(state, step=step, block=block,
-                                       sharded_axes=sharded_axes)
-        self.tracer.record("checkpoint", "ckpt.save", self.task,
-                           self.instance, t0, time.monotonic(), step=out,
-                           blocking=block)
+        with self.tracer.span("checkpoint", "ckpt.save", self.task,
+                              self.instance, blocking=block) as args:
+            out = self.recovery.checkpoint(state, step=step, block=block,
+                                           sharded_axes=sharded_axes)
+            args["step"] = out
         return out
 
     def rescale(self, task: Optional[str] = None, *,
@@ -190,12 +188,11 @@ class TaskComm:
             return None
         if self.tracer is None:
             return self.recovery.restore(like)
-        t0 = time.monotonic()
-        out = self.recovery.restore(like)
-        self.tracer.record("checkpoint", "ckpt.restore", self.task,
-                           self.instance, t0, time.monotonic(),
-                           step=out[0] if out is not None else None,
-                           fresh=out is None)
+        with self.tracer.span("checkpoint", "ckpt.restore", self.task,
+                              self.instance) as args:
+            out = self.recovery.restore(like)
+            args["step"] = out[0] if out is not None else None
+            args["fresh"] = out is None
         return out
 
     # ------------------------------------------------------------- reshard
@@ -316,12 +313,7 @@ class TaskComm:
         if bad:
             raise ValueError(f"dst ranks {bad} out of range for the "
                              f"{len(dst)}-block decomposition of {rspec}")
-        pc = plan_cache()
-        hits0 = pc.hits  # plan-cache verdict for the reshard span (traced
-        cache = None     # runs only; racy across threads, advisory only)
-        plan = pc.get(src_boxes, dst, gshape, arr.dtype)
-        if self.tracer is not None:
-            cache = "hit" if pc.hits > hits0 else "miss"
+        plan = plan_cache().get(src_boxes, dst, gshape, arr.dtype)
 
         if slab_box is not None:
             # an instance reshards what it was shipped: every wanted dst box
@@ -362,24 +354,27 @@ class TaskComm:
                 f"pack_mode={plan.pack_mode!r}, slab={slab_box!r})")
         from .datamodel import transport_stats
         transport_stats().record_reshard(pack=can_pack)
-        tr = self.tracer
-        t0 = time.monotonic()
-        if can_pack:
-            out = execute_pack_jax_all(plan, arr, slab_box=slab_box,
-                                       ranks=wanted)
-        else:
+
+        def execute():
+            if can_pack:
+                return execute_pack_jax_all(plan, arr, slab_box=slab_box,
+                                            ranks=wanted)
             np_arr = np.asarray(arr)
             if slab_box is not None:
                 # scatter straight out of the slab (src_boxes == [slab_box])
-                out = plan.execute([np_arr], ranks=wanted)
-            else:
-                out = plan.execute_global(np_arr, ranks=wanted)
-        if tr is not None:
-            tr.record("reshard",
-                      "reshard.pack" if can_pack else "reshard.numpy",
-                      self.task, self.instance, t0, time.monotonic(),
-                      bytes=int(arr.nbytes), cache=cache,
-                      ranks=len(wanted))
+                return plan.execute([np_arr], ranks=wanted)
+            return plan.execute_global(np_arr, ranks=wanted)
+
+        tr = self.tracer
+        if tr is None:
+            return execute()
+        name = "reshard.pack" if can_pack else "reshard.numpy"
+        with tr.span("reshard", name, self.task, self.instance,
+                     bytes=int(arr.nbytes), ranks=len(wanted)):
+            out = execute()
+            if can_pack:
+                # the span ends with the device work, not its dispatch
+                jax.block_until_ready(out)
         return out
 
 

@@ -101,15 +101,17 @@ class TransportStats:
     """Process-wide counters for data-movement work in the transport path.
 
     ``bytes_copied`` counts actual buffer materializations (eager copies in
-    the legacy path, deferred CoW copies in the fast path); ``views`` counts
-    zero-copy dataset views handed out.  Benchmarks reset + read these to
-    measure the Fig. 4 overhead lever.
+    the legacy path, deferred CoW copies in the fast path); ``bytes_d2h``
+    the part of those copies fetched from a device array first; ``views``
+    counts zero-copy dataset views handed out.  Benchmarks reset + read
+    these to measure the Fig. 4 overhead lever.
     """
 
     def __init__(self) -> None:
         self._lock = make_lock("leaf:transport_stats")
         self.copies = 0
         self.bytes_copied = 0
+        self.bytes_d2h = 0
         self.cow_copies = 0
         self.views = 0
         # M->N redistribution accounting (planned vs shipped vs whole-file):
@@ -143,10 +145,14 @@ class TransportStats:
         self.reshard_pack = 0
         self.reshard_numpy = 0
 
-    def record_copy(self, nbytes: int, cow: bool = False) -> None:
+    def record_copy(self, nbytes: int, cow: bool = False,
+                    d2h: int = 0) -> None:
+        """One buffer copy of ``nbytes``; ``d2h`` bytes of it came from a
+        device array."""
         with self._lock:
             self.copies += 1
             self.bytes_copied += int(nbytes)
+            self.bytes_d2h += int(d2h)
             if cow:
                 self.cow_copies += 1
 
@@ -193,6 +199,7 @@ class TransportStats:
             return {
                 "copies": self.copies,
                 "bytes_copied": self.bytes_copied,
+                "bytes_d2h": self.bytes_d2h,
                 "cow_copies": self.cow_copies,
                 "views": self.views,
                 "redist_planned_bytes": self.redist_planned_bytes,
@@ -212,6 +219,7 @@ class TransportStats:
     def reset(self) -> None:
         with self._lock:
             self.copies = self.bytes_copied = self.cow_copies = self.views = 0
+            self.bytes_d2h = 0
             self.redist_planned_bytes = self.redist_shipped_bytes = 0
             self.redist_baseline_bytes = 0
             self.redist_aligned = self.redist_slabs = 0
@@ -382,7 +390,10 @@ class Dataset:
         data: Optional[np.ndarray] = None,
         parent: Optional["Group"] = None,
         copy: bool = True,
+        trace: Optional[Tuple[Any, str, int, int]] = None,
     ):
+        """``trace``: ``(recorder, task, instance, step)`` of a traced
+        workflow's write, which times the snapshot of ``data`` as spans."""
         self.name = name
         self.shape = tuple(int(s) for s in shape)
         self.dtype = np.dtype(dtype)
@@ -404,9 +415,14 @@ class Dataset:
                 # alias the caller can mutate behind its back -- one copy at
                 # creation buys a sound invariant: every Dataset buffer is
                 # reachable only through Datasets.
-                out = np.array(arr, dtype=self.dtype, order="C")
-                _TRANSPORT_STATS.record_copy(out.nbytes)
-                self._data = out
+                if trace is None:
+                    self._data = self._snapshot(arr)
+                else:
+                    tr, task, instance, step = trace
+                    with tr.span("datamodel", "datamodel.snapshot", task,
+                                 instance, step=step, bytes=int(arr.nbytes),
+                                 device=is_device_array(arr)):
+                        self._data = self._snapshot(arr, trace)
             else:
                 # Internal zero-copy path (spill load, legacy filter): the
                 # caller guarantees nothing else writes this buffer.  A
@@ -416,6 +432,26 @@ class Dataset:
                 self._data = arr
         else:
             self._data = np.zeros(self.shape, dtype=self.dtype)
+
+    def _snapshot(self, arr: Any,
+                  trace: Optional[Tuple[Any, str, int, int]] = None
+                  ) -> np.ndarray:
+        """A private C-ordered host copy of ``arr``.  A device array is
+        fetched to the host first (``np.asarray``), then copied, so the
+        fetch can be timed alone as ``datamodel.d2h``."""
+        d2h = 0
+        if is_device_array(arr):
+            d2h = int(arr.nbytes)
+            if trace is None:
+                arr = np.asarray(arr)
+            else:
+                tr, task, instance, step = trace
+                with tr.span("datamodel", "datamodel.d2h", task, instance,
+                             step=step, bytes=d2h):
+                    arr = np.asarray(arr)
+        out = np.array(arr, dtype=self.dtype, order="C")
+        _TRANSPORT_STATS.record_copy(out.nbytes, d2h=d2h)
+        return out
 
     # -- copy-on-write ------------------------------------------------------
     def _acquire_share(self) -> Tuple[_Share, np.ndarray]:
@@ -504,12 +540,14 @@ class Dataset:
                 # (torn-copy race), and a concurrent ``view()`` must never
                 # observe the new private buffer paired with the old share
                 # (torn-capture race -- see _acquire_share).
+                d2h = is_device_array(self._data)
                 new = np.array(self._data)
                 share.count -= 1
                 self._data = new
                 self._share = _Share(1)
                 break
-        _TRANSPORT_STATS.record_copy(new.nbytes, cow=True)
+        _TRANSPORT_STATS.record_copy(new.nbytes, cow=True,
+                                     d2h=new.nbytes if d2h else 0)
 
     # -- HDF5-ish surface ---------------------------------------------------
     @property
@@ -596,7 +634,9 @@ class Group:
         dtype: Any = None,
         data: Optional[np.ndarray] = None,
         copy: bool = True,
+        trace: Optional[Tuple[Any, str, int, int]] = None,
     ) -> Dataset:
+        """A new dataset at ``path``; ``trace`` as for ``Dataset``."""
         comps = split_path(path)
         if not comps:
             raise ValueError("empty dataset path")
@@ -608,7 +648,8 @@ class Group:
             dtype = data.dtype if dtype is None else dtype
         if shape is None or dtype is None:
             raise ValueError("need shape+dtype or data")
-        ds = Dataset(comps[-1], tuple(shape), dtype, data=data, parent=parent, copy=copy)
+        ds = Dataset(comps[-1], tuple(shape), dtype, data=data, parent=parent,
+                     copy=copy, trace=trace)
         parent.children[comps[-1]] = ds
         return ds
 

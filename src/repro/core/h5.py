@@ -56,11 +56,18 @@ class _H5File:
     # -- writes ---------------------------------------------------------
     def create_dataset(self, path: str, shape=None, dtype=None, data=None,
                        ownership: Optional[datamodel.BlockOwnership] = None):
-        ds = self._inner.create_dataset(path, shape=shape, dtype=dtype, data=data)
+        vol = self._vol
+        tr = vol.tracer if vol is not None else None
+        # a traced workflow times the snapshot of ``data`` as this write's
+        # step: the close the VOL counts next
+        trace = None if tr is None else (
+            tr, vol.task, vol.instance, vol.file_close_counter)
+        ds = self._inner.create_dataset(path, shape=shape, dtype=dtype,
+                                        data=data, trace=trace)
         if ownership is not None:
             ds.ownership = ownership
-        if self._vol is not None:
-            self._vol.on_dataset_write(ds)
+        if vol is not None:
+            vol.on_dataset_write(ds)
         return ds
 
     def require_group(self, path: str):

@@ -28,7 +28,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..analysis.lockcheck import make_lock, sched_point
-from ..obs.recorder import flow_id
+from ..obs.recorder import NO_ANNOTATION, flow_id
 from .channel import (NO_DATA, Channel, ChannelMux, enter_mux_wait_scope,
                       exit_mux_wait_scope)
 from .datamodel import BlockOwnership, File, compile_file_pattern
@@ -224,7 +224,21 @@ class VOL:
         self._open_files[f.filename] = f
 
     def on_file_close(self, f: File) -> None:
-        t0 = time.monotonic()
+        tr = self.tracer  # local: run teardown may detach it concurrently
+        if tr is None:
+            self._close(f)
+        else:
+            # lifecycle span, not a wait: the rendezvous-blocked portion is
+            # claimed by the nested channel.offer spans, the rest is serve
+            # work (filter/slab/spill) on the producer's own clock
+            with tr.span("vol", "vol.close", self.task, self.instance,
+                         step=self.file_close_counter, filename=f.filename):
+                self._close(f)
+        sched = self.scheduler  # local: run teardown may detach it
+        if sched is not None:
+            sched.notify_step("file_close")
+
+    def _close(self, f: File) -> None:
         sup = self.supervisor  # local: the driver may detach it concurrently
         if sup is not None:
             # every step boundary is a health signal for the stall watchdog
@@ -247,17 +261,6 @@ class VOL:
             # exactly LowFive's serve-on-close convention.
             self.serve_all(True, True)
             self.clear_files()
-        tr = self.tracer  # local: the driver may detach it concurrently
-        if tr is not None:
-            # lifecycle span, not a wait: the rendezvous-blocked portion is
-            # claimed by the nested channel.offer spans, the rest is serve
-            # work (filter/slab/spill) on the producer's own clock
-            tr.record("vol", "vol.close", self.task, self.instance, t0,
-                      time.monotonic(), step=self.file_close_counter - 1,
-                      filename=f.filename)
-        sched = self.scheduler  # local: the driver may detach it concurrently
-        if sched is not None:
-            sched.notify_step("file_close")
 
     def on_file_open(self, filename: str) -> Optional[File]:
         """Consumer-side open: pull the next version from a matching channel.
@@ -284,61 +287,72 @@ class VOL:
             c.add_listener(mux)
             # advertise the blocked consumer so `latest` producers serve us
             c.set_consumer_waiting(True)
+        tr = self.tracer  # local: run teardown may detach it concurrently
         t0 = time.monotonic()
         # nested-wait guard: this loop accounts the whole multiplexed wait
         # itself, so a get() issued on one of these channels from inside the
         # scope must not add the same wall time to consumer_wait_s again
         scope = enter_mux_wait_scope(chans)
         try:
-            while True:
-                token = mux.token()
-                any_live = False
-                # the wait ends when data is FOUND; delivery work after the
-                # take (future result on a prefetch miss, spill load) is
-                # accounted by prefetch_blocked_s, never re-counted as wait
-                t_scan = time.monotonic()
-                for c in chans:
-                    r = c.try_get()
-                    if r is NO_DATA:
-                        any_live = True
-                    elif r is not None:
-                        # under the channel lock: every other writer of
-                        # consumer_wait_s holds it, and += on a float is
-                        # read-modify-write -- a concurrent get() on a
-                        # sibling consumer could otherwise lose the update
-                        with c._lock:
-                            c.stats.consumer_wait_s += t_scan - t0
-                        # wait accounted: callbacks below may block anew
-                        exit_mux_wait_scope(scope)
-                        step = self.file_open_counter
-                        self.file_open_counter += 1
-                        tr = self.tracer  # local: driver may detach it
-                        if tr is not None:
-                            tr.record("vol", "vol.open.wait", self.task,
-                                      self.instance, t0, t_scan, step=step,
-                                      flow=("f", flow_id(c.name,
-                                                         c.delivered_seq)),
-                                      edge=c.name)
-                        if sup is not None:
-                            # fault point "recv": the payload WAS delivered
-                            # (the channel's watermark moved, the replay
-                            # buffer recorded it) but the task never saw it
-                            # -- the window only the replay protocol covers
-                            sup.fire(self.task, self.instance, "recv", step)
-                        self._fire("after_file_open", r)
-                        sched = self.scheduler  # local: driver may detach it
-                        if sched is not None:
-                            sched.notify_step("file_open")
-                        return r
-                if not any_live:
-                    return None  # all producers report all-done (query protocol)
-                if sup is not None:
-                    # bounded sleep + heartbeat: a consumer parked in the
-                    # fan-in mux is starved, not stalled (watchdog hysteresis)
-                    sup.heartbeat(self.task, self.instance)
-                    mux.wait(token, timeout=sup.wait_quantum(self.task))
-                else:
-                    mux.wait(token)
+            found = None
+            # the profiler annotation covers the scans and waits; the span
+            # recorded below ends where the data was found
+            with NO_ANNOTATION if tr is None else tr.annotate("vol.open.wait"):
+                while found is None:
+                    token = mux.token()
+                    any_live = False
+                    # the wait ends when data is FOUND; delivery work after
+                    # the take (future result on a prefetch miss, spill load)
+                    # is accounted by prefetch_blocked_s, never re-counted as
+                    # wait
+                    t_scan = time.monotonic()
+                    for c in chans:
+                        r = c.try_get(step=self.file_open_counter)
+                        if r is NO_DATA:
+                            any_live = True
+                        elif r is not None:
+                            found = c, r
+                            break
+                    if found is not None:
+                        break
+                    if not any_live:
+                        # all producers report all-done (query protocol)
+                        return None
+                    if sup is not None:
+                        # bounded sleep + heartbeat: a consumer parked in the
+                        # fan-in mux is starved, not stalled (watchdog
+                        # hysteresis)
+                        sup.heartbeat(self.task, self.instance)
+                        mux.wait(token, timeout=sup.wait_quantum(self.task))
+                    else:
+                        mux.wait(token)
+            c, r = found
+            # under the channel lock: every other writer of consumer_wait_s
+            # holds it, and += on a float is read-modify-write -- a
+            # concurrent get() on a sibling consumer could otherwise lose the
+            # update
+            with c._lock:
+                c.stats.consumer_wait_s += t_scan - t0
+            # wait accounted: callbacks below may block anew
+            exit_mux_wait_scope(scope)
+            step = self.file_open_counter
+            self.file_open_counter += 1
+            if tr is not None:
+                tr.record("vol", "vol.open.wait", self.task, self.instance,
+                          t0, t_scan, step=step,
+                          flow=("f", flow_id(c.name, c.delivered_seq)),
+                          edge=c.name)
+            if sup is not None:
+                # fault point "recv": the payload WAS delivered (the
+                # channel's watermark moved, the replay buffer recorded it)
+                # but the task never saw it -- the window only the replay
+                # protocol covers
+                sup.fire(self.task, self.instance, "recv", step)
+            self._fire("after_file_open", r)
+            sched = self.scheduler  # local: run teardown may detach it
+            if sched is not None:
+                sched.notify_step("file_open")
+            return r
         finally:
             exit_mux_wait_scope(scope)  # idempotent on the delivery path
             for c in chans:

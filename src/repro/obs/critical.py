@@ -5,6 +5,9 @@ latency on the slowest ("critical") instance -- to WHERE the time went:
 
 ``block``      rendezvous waits (``channel.offer`` / ``channel.get`` block
                intervals, ``vol.open`` mux waits)
+``copy``       the data model's snapshot of caller data in
+               ``create_dataset`` (``datamodel.*``: the device->host fetch
+               and the host copy)
 ``prep``       prefetch preparation the consumer actually blocked on
 ``reshard``    pack/numpy redistribute executes
 ``checkpoint`` checkpoint save/restore
@@ -29,12 +32,14 @@ from typing import Any, Dict, List, Optional, Tuple
 __all__ = ["attribute", "critical_path", "per_edge", "format_report"]
 
 #: claim precedence (outer blocking states absorb nested work)
-PRECEDENCE = ("block", "prep", "reshard", "checkpoint", "recovery", "rescale")
+PRECEDENCE = ("block", "copy", "prep", "reshard", "checkpoint", "recovery",
+              "rescale")
 
 #: span category -> attribution bucket
-_BUCKET = {"channel": "block", "vol": "block", "prefetch": "prep",
-           "reshard": "reshard", "checkpoint": "checkpoint",
-           "recovery": "recovery", "rescale": "rescale"}
+_BUCKET = {"channel": "block", "vol": "block", "datamodel": "copy",
+           "prefetch": "prep", "reshard": "reshard",
+           "checkpoint": "checkpoint", "recovery": "recovery",
+           "rescale": "rescale"}
 
 
 def _bucket_of(s: Dict[str, Any]) -> Optional[str]:

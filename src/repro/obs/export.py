@@ -6,18 +6,19 @@ chrome://tracing:
 * one **track per task instance** (pid = task, tid = instance, named via
   ``M`` metadata events); prefetch-pool preps get their own ``pool``
   process so overlapping worker spans never stack onto a task's track;
-* **flow arrows** from a producer's ``channel.offer`` span to the
-  consumer's ``channel.get``/``vol.open`` span for the same (edge, seq)
-  hand-off (``ph: s``/``f`` pairs keyed by :func:`..recorder.flow_id`);
-* **counter tracks** for queue depth / in-flight preps / cumulative bytes
-  (sampled by the channel hooks and, when a ``TelemetryTimeline`` is
-  merged, by the scheduler's per-tick rows);
+* **flow arrows** from a producer's ``channel.offer`` span through the
+  prefetch prep and wait to the consumer's ``channel.get``/``vol.open``
+  span for the same (edge, seq) hand-off (``ph: s``/``t``/``f`` keyed by
+  :func:`..recorder.flow_id`);
+* **counter tracks** for queue depth / in-flight preps, sampled by the
+  channel hooks;
 * ``TelemetryTimeline`` lifecycle events (restart / drop / rescale /
   stall) merged as **instant events** on the affected task's track -- one
   unified timeline artifact instead of two half-views.
 
 ``load_trace`` inverts ``to_chrome`` back into recorder-style span dicts
-(category, task, instance, monotonic seconds), which is what the critical
+(category, task, instance, span ``id``/``parent``, monotonic seconds),
+which is what the critical
 -path analyzer and the ``python -m repro.obs report`` CLI consume -- the
 exported file IS the offline analysis input, there is no second format.
 """
@@ -34,9 +35,10 @@ _TIMELINE_INSTANTS = ("restart", "drop", "rescale", "stall")
 
 
 def merge_timeline(timeline: Any) -> List[Dict[str, Any]]:
-    """Convert a ``TelemetryTimeline`` into recorder-style span dicts:
-    lifecycle events -> ``ph: i`` on the task's track, sampled per-edge
-    rows -> ``ph: C`` counter samples (queue depth + in-flight preps)."""
+    """Convert a ``TelemetryTimeline``'s lifecycle events into
+    recorder-style ``ph: i`` instants on the task's track.  Its per-tick
+    edge rows are left out: the channel hooks already sample queue depth
+    and in-flight preps onto the same counter tracks."""
     out: List[Dict[str, Any]] = []
     if timeline is None:
         return out
@@ -50,16 +52,6 @@ def merge_timeline(timeline: Any) -> List[Dict[str, Any]]:
                     "instance": int(ev.get("instance", 0)),
                     "t0": ev["t"], "t1": ev["t"], "step": None,
                     "flow": None, "args": args or None})
-    for row in timeline.samples():
-        edge = row.get("edge", "?")
-        t = row["t"]
-        for field, track in (("queue_len", "qdepth"),
-                             ("inflight", "inflight")):
-            if field in row:
-                out.append({"ph": "C", "cat": "counter",
-                            "name": f"{track}:{edge}", "task": "counters",
-                            "instance": 0, "t0": t, "t1": t, "step": None,
-                            "flow": None, "args": {"value": row[field]}})
     return out
 
 
@@ -101,6 +93,10 @@ def to_chrome(spans: List[Dict[str, Any]],
         args["_cat"] = s["cat"]
         args["_task"] = s["task"]
         args["_instance"] = s["instance"]
+        if s.get("id") is not None:
+            args["_id"] = s["id"]
+        if s.get("parent") is not None:
+            args["_parent"] = s["parent"]
         if s["ph"] == "X":
             events.append({"ph": "X", "name": s["name"], "cat": s["cat"],
                            "pid": pid, "tid": tid, "ts": us(s["t0"]),
@@ -112,7 +108,7 @@ def to_chrome(spans: List[Dict[str, Any]],
                 ev = {"ph": role, "name": "handoff", "cat": "flow",
                       "id": int(fid), "pid": pid, "tid": tid,
                       "ts": us(s["t1"] if role == "s" else s["t0"])}
-                if role == "f":
+                if role != "s":
                     ev["bp"] = "e"  # bind to the enclosing slice
                 events.append(ev)
         elif s["ph"] == "i":
@@ -146,7 +142,7 @@ def load_trace(path: str) -> List[Dict[str, Any]]:
     t_origin = float(doc.get("otherData", {}).get("t_origin_monotonic", 0.0))
     flows: Dict[Tuple[int, int, float], Tuple[str, int]] = {}
     for ev in doc.get("traceEvents", []):
-        if ev.get("ph") in ("s", "f"):
+        if ev.get("ph") in ("s", "t", "f"):
             flows[(ev["pid"], ev["tid"], ev["ts"])] = (ev["ph"], ev["id"])
     out: List[Dict[str, Any]] = []
     for ev in doc.get("traceEvents", []):
@@ -166,6 +162,8 @@ def load_trace(path: str) -> List[Dict[str, Any]]:
         task = args.pop("_task", "?")
         instance = int(args.pop("_instance", ev.get("tid", 1) - 1))
         step = args.pop("step", None)
+        sid = args.pop("_id", None)
+        parent = args.pop("_parent", None)
         t0 = t_origin + ev["ts"] / 1e6
         t1 = t0 + (ev.get("dur", 0.0) / 1e6 if ph == "X" else 0.0)
         flow: Optional[Tuple[str, int]] = None
@@ -176,9 +174,12 @@ def load_trace(path: str) -> List[Dict[str, Any]]:
                 if hit is not None:
                     flow = hit
                     break
-        out.append({"ph": "X" if ph == "X" else "i", "cat": cat,
-                    "name": ev["name"], "task": task, "instance": instance,
-                    "t0": t0, "t1": t1, "step": step, "flow": flow,
-                    "args": args or None})
+        span = {"ph": "X" if ph == "X" else "i", "cat": cat,
+                "name": ev["name"], "task": task, "instance": instance,
+                "t0": t0, "t1": t1, "step": step, "flow": flow,
+                "args": args or None}
+        if ph == "X":
+            span["id"], span["parent"] = sid, parent
+        out.append(span)
     out.sort(key=lambda s: (s["t0"], s["t1"]))
     return out

@@ -19,6 +19,12 @@ final -- instrumented sites time their interval locally and report it
 closed, with an ``aborted`` arg when the interval ended in an interrupt /
 poison / crash instead of a delivery.  There is nothing to leak across a
 restart or rescale; the span-lifecycle test asserts exactly that.
+``span()`` is the one-block form of the same thing: it times the block,
+records it on exit (``aborted`` when the block raised) and meanwhile holds
+a ``jax.profiler.TraceAnnotation("wilkins/<name>")`` open on the thread,
+so a profiler trace of the run shows the span on the device trace's clock.
+Each span gets an ``id`` and, from a per-thread stack of open ``span()``
+blocks, the ``parent`` id of the block that encloses it.
 
 The **flight recorder** is a bounded per-shard ring of the most recent
 spans; ``mark_failure(reason)`` snapshots the merged ring into
@@ -29,20 +35,40 @@ instance was doing, alongside the chained error.
 
 from __future__ import annotations
 
+import itertools
+import sys
 import threading
 import time
 import zlib
 from collections import deque
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..analysis.lockcheck import make_lock
 
-__all__ = ["TraceConfig", "SpanRecorder", "flow_id", "span_categories"]
+__all__ = ["TraceConfig", "SpanRecorder", "flow_id", "span_categories",
+           "NO_ANNOTATION"]
 
 #: span taxonomy -- one category per instrumented layer (DESIGN.md
 #: "Observability & tracing" documents the member spans of each)
 CATEGORIES = ("vol", "channel", "prefetch", "reshard", "checkpoint",
-              "recovery", "rescale", "task", "counter", "timeline")
+              "datamodel", "recovery", "rescale", "task", "counter",
+              "timeline")
+
+#: what a site enters in place of ``SpanRecorder.annotate`` when untraced
+#: (one shared object: an untraced site allocates nothing)
+NO_ANNOTATION = nullcontext()
+
+
+def _annotation(name: str) -> Any:
+    """A ``TraceAnnotation`` for span ``name``, or ``None`` when jax was
+    never imported (no profiler can be running then, and importing jax
+    here would cost a numpy-only run its import time)."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation("wilkins/" + name)
+
 
 # process-wide construction counter: the zero-cost test asserts an untraced
 # run leaves it unchanged (no recorder, hence no spans, was ever allocated)
@@ -174,19 +200,71 @@ class SpanRecorder:
         self.failure_dumps: List[Dict[str, Any]] = []
         self._dump_lock = make_lock("leaf:obs_dumps")
         self.t_origin = time.monotonic()
+        self._ids = itertools.count(1)
+        self._tls = threading.local()  # .stack: ids of the open span() blocks
         with _created_lock:
             _CREATED += 1
 
     # ------------------------------------------------------------- recording
+    def _stack(self) -> List[int]:
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        return stack
+
     def record(self, cat: str, name: str, task: str, instance: int,
                t0: float, t1: float, step: Optional[int] = None,
                flow: Optional[Tuple[str, int]] = None, **args: Any) -> None:
         """One closed duration span (Perfetto "X").  ``flow`` is
-        ``("s", id)`` on the producing side of a hand-off and ``("f", id)``
-        on the consuming side; the exporter turns the pair into an arrow."""
+        ``("s", id)`` on the producing side of a hand-off, ``("f", id)`` on
+        the consuming side and ``("t", id)`` on a step between them (the
+        prefetch prep and wait); the exporter turns them into an arrow.
+        Its ``parent`` is the innermost ``span()`` open on this thread."""
+        stack = self._stack()
         self._push({"ph": "X", "cat": cat, "name": name, "task": task,
                     "instance": instance, "t0": t0, "t1": t1, "step": step,
-                    "flow": flow, "args": args or None})
+                    "flow": flow, "args": args or None,
+                    "id": next(self._ids),
+                    "parent": stack[-1] if stack else None})
+
+    @contextmanager
+    def span(self, cat: str, name: str, task: str, instance: int,
+             step: Optional[int] = None,
+             flow: Optional[Tuple[str, int]] = None, **args: Any):
+        """Time the ``with`` block as one span and record it on exit, with
+        ``aborted=True`` when the block raised.  The block runs inside a
+        ``wilkins/<name>`` profiler annotation; it may add args through the
+        dict it is given, and set the span's ``step`` there once known."""
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        sid = next(self._ids)
+        ann = _annotation(name)
+        if ann is not None:
+            ann.__enter__()
+        stack.append(sid)
+        t0 = time.monotonic()
+        try:
+            yield args
+        except BaseException:
+            args["aborted"] = True
+            raise
+        finally:
+            t1 = time.monotonic()
+            stack.pop()
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            step = args.pop("step", step)
+            self._push({"ph": "X", "cat": cat, "name": name, "task": task,
+                        "instance": instance, "t0": t0, "t1": t1,
+                        "step": step, "flow": flow, "args": args or None,
+                        "id": sid, "parent": parent})
+
+    def annotate(self, name: str) -> Any:
+        """The profiler annotation of span ``name`` alone, for a site that
+        times its interval itself and ``record()``s it inside: wait loops
+        with several exits."""
+        ann = _annotation(name)
+        return NO_ANNOTATION if ann is None else ann
 
     def instant(self, cat: str, name: str, task: str, instance: int,
                 t: Optional[float] = None, **args: Any) -> None:

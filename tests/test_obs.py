@@ -235,11 +235,19 @@ def _span(cat, name, task, inst, t0, t1, **args):
             "flow": None, "args": args or None}
 
 
+BUCKETS = ("block", "copy", "prep", "reshard", "checkpoint", "recovery",
+           "rescale", "compute")
+
+
 def test_attribution_synthetic_known_answer():
     spans = [
-        # window [0, 10]; block [1, 4]; reshard [3, 5] (overlap claimed by
-        # block first -> reshard nets 1s); checkpoint [8, 9]
+        # window [0, 10]; block [1, 4]; a snapshot [4.5, 7.5] with its
+        # device->host fetch nested in it (copy, claimed after block and
+        # before reshard); reshard [3, 5] (block and copy claim [3, 4] and
+        # [4.5, 5] first -> reshard nets 0.5s); checkpoint [8, 9]
         _span("channel", "channel.get", "c", 0, 1.0, 4.0, edge="e"),
+        _span("datamodel", "datamodel.snapshot", "c", 0, 4.5, 7.5),
+        _span("datamodel", "datamodel.d2h", "c", 0, 4.5, 7.0),
         _span("reshard", "reshard.numpy", "c", 0, 3.0, 5.0, edge=None),
         _span("checkpoint", "ckpt.save", "c", 0, 8.0, 9.0),
         _span("task", "task.window", "c", 0, 0.0, 10.0),
@@ -248,11 +256,11 @@ def test_attribution_synthetic_known_answer():
     row = rep["instances"]["c[0]"]
     assert row["window_s"] == pytest.approx(10.0)
     assert row["block"] == pytest.approx(3.0)
-    assert row["reshard"] == pytest.approx(1.0)
+    assert row["copy"] == pytest.approx(3.0)
+    assert row["reshard"] == pytest.approx(0.5)
     assert row["checkpoint"] == pytest.approx(1.0)
-    assert row["compute"] == pytest.approx(5.0)
-    total = sum(row[b] for b in ("block", "prep", "reshard", "checkpoint",
-                                 "recovery", "rescale", "compute"))
+    assert row["compute"] == pytest.approx(2.5)
+    total = sum(row[b] for b in BUCKETS)
     assert total == pytest.approx(row["window_s"], abs=1e-12)
     assert critical_path(spans) == "c[0]"
     text = format_report(rep)
@@ -328,9 +336,7 @@ tasks:
     att = rep.critical_path
     assert att["instances"]
     for key, row in att["instances"].items():
-        total = sum(row[b] for b in ("block", "prep", "reshard",
-                                     "checkpoint", "recovery", "rescale",
-                                     "compute"))
+        total = sum(row[b] for b in BUCKETS)
         assert total == pytest.approx(row["window_s"], abs=1e-9), key
     edges = att["edges"]
     slow_edge = next(e for e in edges if "a.h5" in e)
@@ -345,9 +351,7 @@ tasks:
     assert crit.startswith(("slow", "sink"))
     # per-step rows exist on the critical instance and sum to latency
     for step, row in att["steps"].items():
-        total = sum(row[b] for b in ("block", "prep", "reshard",
-                                     "checkpoint", "recovery", "rescale",
-                                     "compute"))
+        total = sum(row[b] for b in BUCKETS)
         assert total == pytest.approx(row["latency_s"], rel=0.05), step
 
 
@@ -637,3 +641,262 @@ def test_obs_report_cli_empty_trace(tmp_path, capsys):
     json.dump({"traceEvents": []}, open(path, "w"))
     from repro.obs.__main__ import main
     assert main(["report", path]) == 1
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock: span(), annotations, the snapshot spans
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def annotations(monkeypatch):
+    """Every ``wilkins/`` profiler annotation opened, by name, with how many
+    are open still (``jax.profiler.TraceAnnotation`` counted)."""
+    import jax.profiler
+
+    real = jax.profiler.TraceAnnotation
+    seen = {"opened": [], "open": 0}
+
+    class Counting:
+        def __init__(self, name, **kw):
+            self.name = name
+            self._inner = real(name, **kw)
+
+        def __enter__(self):
+            if self.name.startswith("wilkins/"):
+                seen["opened"].append(self.name)
+                seen["open"] += 1
+            return self._inner.__enter__()
+
+        def __exit__(self, *exc):
+            if self.name.startswith("wilkins/"):
+                seen["open"] -= 1
+            return self._inner.__exit__(*exc)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    return seen
+
+
+def test_span_nests_parents_and_marks_aborted(annotations):
+    rec = SpanRecorder(TraceConfig(shards=1))
+    with rec.span("vol", "vol.close", "p", 0, step=3, filename="o.h5"):
+        with rec.span("datamodel", "datamodel.snapshot", "p", 0, step=3,
+                      bytes=8) as args:
+            args["extra"] = 1
+            assert annotations["open"] == 2
+        rec.record("channel", "channel.offer", "p", 0, 0.0, 0.0, step=3)
+        with pytest.raises(ValueError):
+            with rec.span("datamodel", "datamodel.d2h", "p", 0, step=3):
+                raise ValueError("fetch failed")
+    assert annotations["open"] == 0
+    assert annotations["opened"] == ["wilkins/vol.close",
+                                     "wilkins/datamodel.snapshot",
+                                     "wilkins/datamodel.d2h"]
+    by = {s["name"]: s for s in rec.spans()}
+    close = by["vol.close"]
+    assert close["parent"] is None and close["step"] == 3
+    for child in ("datamodel.snapshot", "channel.offer", "datamodel.d2h"):
+        assert by[child]["parent"] == close["id"], child
+    assert len({s["id"] for s in by.values()}) == 4
+    assert by["datamodel.snapshot"]["args"] == {"bytes": 8, "extra": 1}
+    assert by["datamodel.d2h"]["args"] == {"aborted": True}
+    assert "aborted" not in close["args"]
+    assert close["t0"] <= by["datamodel.snapshot"]["t0"] <= \
+        by["datamodel.snapshot"]["t1"] <= close["t1"]
+
+
+def test_span_step_set_inside_the_block():
+    rec = SpanRecorder(TraceConfig(shards=1))
+    with rec.span("checkpoint", "ckpt.save", "p", 0) as args:
+        args["step"] = 7
+    (s,) = rec.spans()
+    assert s["step"] == 7 and s["args"] is None
+
+
+def test_parents_are_per_thread():
+    rec = SpanRecorder(TraceConfig(shards=2))
+    inside = threading.Event()
+    done = threading.Event()
+
+    def other():
+        inside.wait(10)
+        rec.record("channel", "channel.get", "c", 0, 0.0, 0.0)
+        done.set()
+
+    th = threading.Thread(target=other)
+    th.start()
+    with rec.span("vol", "vol.close", "p", 0):
+        inside.set()
+        assert done.wait(10)
+    th.join(10)
+    get = next(s for s in rec.spans() if s["name"] == "channel.get")
+    assert get["parent"] is None
+
+
+def _device_workflow(tmp_path, tag):
+    import jax.numpy as jnp
+
+    yaml = """
+tasks:
+  - func: p
+    outports: [{filename: o.h5, dsets: [{name: /g, memory: 1}]}]
+  - func: c
+    inports:
+      - {filename: o.h5, redistribute: 1, prefetch: 1,
+         dsets: [{name: /g, memory: 1}]}
+"""
+
+    def p():
+        for t in range(3):
+            with h5.File("o.h5", "w") as f:
+                f.create_dataset("/g", data=jnp.arange(32.0) + t)
+
+    def c():
+        while h5.File("o.h5", "r") is not None:
+            pass
+
+    return Wilkins(yaml, {"p": p, "c": c}, spill_dir=str(tmp_path / tag))
+
+
+def test_untraced_run_constructs_no_annotation(tmp_path, annotations):
+    w = _device_workflow(tmp_path, "off")
+    n0 = created_count()
+    w.run(timeout=60)
+    assert created_count() == n0
+    assert annotations["opened"] == []
+
+
+def test_traced_run_times_the_snapshot(tmp_path, annotations):
+    from repro.core.datamodel import transport_stats
+
+    w = _device_workflow(tmp_path, "on")
+    path = str(tmp_path / "snap.json")
+    d2h0 = transport_stats().snapshot()["bytes_d2h"]
+    w.run(timeout=60, trace=path)
+    assert transport_stats().snapshot()["bytes_d2h"] - d2h0 == 3 * 32 * 4
+    spans = load_trace(path)
+    by = {}
+    for s in spans:
+        by.setdefault(s["name"], []).append(s)
+    snaps, d2hs = by["datamodel.snapshot"], by["datamodel.d2h"]
+    assert [s["step"] for s in snaps] == [0, 1, 2]
+    assert [s["step"] for s in d2hs] == [0, 1, 2]
+    ids = {s["id"]: s for s in snaps}
+    for d in d2hs:
+        parent = ids[d["parent"]]
+        assert parent["t0"] <= d["t0"] <= d["t1"] <= parent["t1"]
+        assert d["args"]["bytes"] == 32 * 4 and d["task"] == "p"
+    assert all(s["args"]["device"] is True and s["args"]["bytes"] == 32 * 4
+               for s in snaps)
+    # the copy bucket takes the snapshot out of the producer's compute
+    att = attribute(spans)
+    assert att["instances"]["p[0]"]["copy"] > 0
+    # every X span the program recorded had its profiler annotation
+    names = {f"wilkins/{s['name']}" for s in spans if s["ph"] == "X"}
+    assert {"wilkins/vol.close", "wilkins/vol.open.wait",
+            "wilkins/channel.offer", "wilkins/prefetch.prep",
+            "wilkins/prefetch.wait", "wilkins/datamodel.snapshot",
+            "wilkins/datamodel.d2h"} <= names
+    assert names <= set(annotations["opened"])
+    assert annotations["open"] == 0
+
+
+def test_prefetch_spans_carry_the_handoff(tmp_path):
+    w = _device_workflow(tmp_path, "flow")
+    path = str(tmp_path / "flow.json")
+    w.run(timeout=60, trace=path)
+    spans = load_trace(path)
+    offers = {s["flow"][1]: s for s in spans if s["name"] == "channel.offer"}
+    assert len(offers) == 3
+    for name in ("prefetch.prep", "prefetch.wait"):
+        got = [s for s in spans if s["name"] == name]
+        assert len(got) == 3, name
+        for s in got:
+            role, fid = s["flow"]
+            assert role == "t" and fid in offers, name
+            assert s["step"] is not None
+    waits = sorted(s["step"] for s in spans if s["name"] == "prefetch.wait")
+    assert waits == [0, 1, 2]
+    doc = json.load(open(path))
+    assert "t" in {ev["ph"] for ev in doc["traceEvents"]}
+
+
+@pytest.mark.parametrize("data, d2h", [("device", 64), ("host", 0)],
+                         ids=["device", "host"])
+def test_bytes_d2h_counts_device_arrays_only(data, d2h):
+    import jax.numpy as jnp
+
+    from repro.core.datamodel import File, transport_stats
+
+    arr = jnp.ones(16, jnp.float32) if data == "device" else \
+        np.ones(16, np.float32)
+    s0 = transport_stats().snapshot()
+    File("x.h5").create_dataset("/g", data=arr)
+    s1 = transport_stats().snapshot()
+    assert s1["bytes_copied"] - s0["bytes_copied"] == 64
+    assert s1["bytes_d2h"] - s0["bytes_d2h"] == d2h
+
+
+def test_traced_reshard_ends_at_the_device_work(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.comm import TaskComm
+    from repro.core.redistribute import RedistSpec
+
+    real = jax.block_until_ready
+    calls = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(x) or real(x))
+    g = jnp.asarray(np.arange(37 * 8, dtype=np.float32).reshape(37, 8))
+    spec = RedistSpec(axis=0, nslots=2, slot=1, nranks=2)
+    untraced = TaskComm().reshard(g, spec, prefer="pack")
+    assert calls == []
+    rec = SpanRecorder(TraceConfig(shards=1))
+    traced = TaskComm(task="c", tracer=rec).reshard(g, spec, prefer="pack")
+    assert len(calls) == 1 and calls[0] is traced
+    for a, b in zip(untraced, traced):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    (s,) = rec.spans()
+    assert s["name"] == "reshard.pack"
+    assert s["args"] == {"bytes": 37 * 8 * 4, "ranks": len(traced)}
+
+
+def test_export_roundtrip_carries_ids_and_steps_of_a_flow(tmp_path):
+    rec = SpanRecorder(TraceConfig(shards=1))
+    t = rec.t_origin
+    fid = flow_id("e", 1)
+    with rec.span("vol", "vol.close", "p", 0, step=0):
+        rec.record("channel", "channel.offer", "p", 0, t, t + 0.1, step=0,
+                   flow=("s", fid), edge="e")
+    rec.record("prefetch", "prefetch.wait", "c", 0, t + 0.2, t + 0.3,
+               step=0, flow=("t", fid), edge="e")
+    path = str(tmp_path / "ids.json")
+    export_trace(path, rec)
+    back = {s["name"]: s for s in load_trace(path)}
+    orig = {s["name"]: s for s in rec.spans()}
+    for name in orig:
+        assert back[name]["id"] == orig[name]["id"]
+        assert back[name]["parent"] == orig[name]["parent"]
+        assert back[name]["flow"] == orig[name]["flow"]
+    assert back["channel.offer"]["parent"] == back["vol.close"]["id"]
+
+
+def test_merge_timeline_keeps_instants_only(tmp_path):
+    from repro.obs import merge_timeline
+
+    class Timeline:
+        def events(self):
+            return [{"t": 1.0, "kind": "restart", "task": "c",
+                     "instance": 0}, {"t": 2.0, "kind": "tick"}]
+
+        def samples(self):
+            return [{"t": 1.5, "edge": "e", "queue_len": 1, "inflight": 0}]
+
+    out = merge_timeline(Timeline())
+    assert [(s["ph"], s["name"]) for s in out] == [("i", "timeline.restart")]
+    # the queue-depth and in-flight tracks come from the channel hooks
+    w = _device_workflow(tmp_path, "tracks")
+    path = str(tmp_path / "tracks.json")
+    w.run(timeout=60, trace=path)
+    tracks = {s["name"].split(":")[0] for s in load_trace(path)
+              if s["ph"] == "C"}
+    assert {"qdepth", "inflight"} <= tracks
