@@ -28,9 +28,15 @@ concern; patterns are compiled once to regexes and LRU-cached (see
 
 Ownership rules (see DESIGN.md):
 
+* ``create_dataset(data=arr)`` snapshots the caller's array.  A host array
+  is copied; a device array's snapshot is its fetched host value, adopted
+  without a second copy when it is read-only, of the Dataset's dtype and
+  contiguous in C or Fortran order (nothing can write it: the ``jax.Array``
+  is immutable).
 * ``Dataset`` mutation goes through ``__setitem__`` / ``write_slab``; both
   materialize a private copy first if the buffer is shared or read-only
-  (memmap).  Copies are counted in ``transport_stats()``.
+  (memmap, adopted device value).  Copies are counted in
+  ``transport_stats()``.
 * ``read_direct`` / ``__getitem__`` return a read-only alias while the buffer
   is shared, so a reader cannot silently corrupt a sibling view.
 """
@@ -102,9 +108,11 @@ class TransportStats:
 
     ``bytes_copied`` counts actual buffer materializations (eager copies in
     the legacy path, deferred CoW copies in the fast path); ``bytes_d2h``
-    the part of those copies fetched from a device array first; ``views``
-    counts zero-copy dataset views handed out.  Benchmarks reset + read
-    these to measure the Fig. 4 overhead lever.
+    every byte fetched from a device array to the host -- a copy's fetched
+    part, and the whole of a snapshot adopted with no host copy
+    (``snapshots_adopted``), so it is not always a part of
+    ``bytes_copied``; ``views`` counts zero-copy dataset views handed out.
+    Benchmarks reset + read these to measure the Fig. 4 overhead lever.
     """
 
     def __init__(self) -> None:
@@ -112,6 +120,7 @@ class TransportStats:
         self.copies = 0
         self.bytes_copied = 0
         self.bytes_d2h = 0
+        self.snapshots_adopted = 0
         self.cow_copies = 0
         self.views = 0
         # M->N redistribution accounting (planned vs shipped vs whole-file):
@@ -155,6 +164,13 @@ class TransportStats:
             self.bytes_d2h += int(d2h)
             if cow:
                 self.cow_copies += 1
+
+    def record_d2h(self, nbytes: int) -> None:
+        """A device array's snapshot adopted as its fetched host value:
+        ``nbytes`` crossed from the device, no host copy was made."""
+        with self._lock:
+            self.bytes_d2h += int(nbytes)
+            self.snapshots_adopted += 1
 
     def record_view(self) -> None:
         with self._lock:
@@ -200,6 +216,7 @@ class TransportStats:
                 "copies": self.copies,
                 "bytes_copied": self.bytes_copied,
                 "bytes_d2h": self.bytes_d2h,
+                "snapshots_adopted": self.snapshots_adopted,
                 "cow_copies": self.cow_copies,
                 "views": self.views,
                 "redist_planned_bytes": self.redist_planned_bytes,
@@ -219,7 +236,7 @@ class TransportStats:
     def reset(self) -> None:
         with self._lock:
             self.copies = self.bytes_copied = self.cow_copies = self.views = 0
-            self.bytes_d2h = 0
+            self.bytes_d2h = self.snapshots_adopted = 0
             self.redist_planned_bytes = self.redist_shipped_bytes = 0
             self.redist_baseline_bytes = 0
             self.redist_aligned = self.redist_slabs = 0
@@ -414,7 +431,8 @@ class Dataset:
                 # Adopting a caller-owned buffer would hand the CoW layer an
                 # alias the caller can mutate behind its back -- one copy at
                 # creation buys a sound invariant: every Dataset buffer is
-                # reachable only through Datasets.
+                # reachable only through Datasets, or is immutable (a
+                # device array's read-only fetched value, see _snapshot).
                 if trace is None:
                     self._data = self._snapshot(arr)
                 else:
@@ -436,9 +454,18 @@ class Dataset:
     def _snapshot(self, arr: Any,
                   trace: Optional[Tuple[Any, str, int, int]] = None
                   ) -> np.ndarray:
-        """A private C-ordered host copy of ``arr``.  A device array is
-        fetched to the host first (``np.asarray``), then copied, so the
-        fetch can be timed alone as ``datamodel.d2h``."""
+        """The Dataset's own host buffer holding ``arr``.
+
+        A device array is fetched to the host (``np.asarray``, timed alone as
+        ``datamodel.d2h``).  Its fetched value is adopted as is when it is
+        read-only, of this dtype and contiguous: the ``jax.Array`` is
+        immutable and JAX donates no buffer with an external reference, so
+        nothing can write it, and the CoW layer copies it before the first
+        write through the Dataset.  The order is the device layout's: a TPU
+        keeps narrow-minor arrays such as (N, 2) column-major, so their
+        fetched value is Fortran-ordered, and a C-ordered copy of it would
+        be a transpose.  Otherwise, and for every host array, the value is
+        copied into a fresh C-ordered buffer."""
         d2h = 0
         if is_device_array(arr):
             d2h = int(arr.nbytes)
@@ -449,6 +476,10 @@ class Dataset:
                 with tr.span("datamodel", "datamodel.d2h", task, instance,
                              step=step, bytes=d2h):
                     arr = np.asarray(arr)
+            if (not arr.flags.writeable and arr.dtype == self.dtype
+                    and arr.flags.forc):
+                _TRANSPORT_STATS.record_d2h(d2h)
+                return arr
         out = np.array(arr, dtype=self.dtype, order="C")
         _TRANSPORT_STATS.record_copy(out.nbytes, d2h=d2h)
         return out

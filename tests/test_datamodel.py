@@ -133,3 +133,95 @@ def test_share_race_view_vs_cow_write():
                 assert not np.shares_memory(v.read_direct(), src.read_direct())
     finally:
         sys.setswitchinterval(old)
+
+
+# ---------------------------------------------------------------------------
+# Snapshot of a device array: the fetched host value is adopted.  On the CPU
+# backend ``np.asarray`` of a jax.Array aliases the device buffer itself, so
+# these are the hard case for the "nothing else can write it" argument.
+# ---------------------------------------------------------------------------
+def _stats_delta(s0):
+    from repro.core.datamodel import transport_stats
+    s1 = transport_stats().snapshot()
+    return {k: s1[k] - s0[k] for k in
+            ("bytes_copied", "bytes_d2h", "snapshots_adopted", "cow_copies")}
+
+
+@pytest.mark.parametrize("major_to_minor", [(0, 1), (1, 0)],
+                         ids=["row_major", "column_major"])
+def test_device_snapshot_adopts_the_fetched_value(major_to_minor):
+    """A TPU lays out narrow-minor arrays such as (N, 2) column-major, and
+    their fetched value is Fortran-ordered: adopted all the same."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.layout import Format, Layout
+
+    from repro.core.datamodel import transport_stats
+
+    want = np.arange(16, dtype=np.uint32).reshape(8, 2)
+    x = jax.device_put(jnp.asarray(want), Format(
+        Layout(major_to_minor=major_to_minor),
+        jax.sharding.SingleDeviceSharding(jax.devices()[0])))
+    s0 = transport_stats().snapshot()
+    ds = File("a.h5").create_dataset("/d", data=x)
+    assert _stats_delta(s0) == {"bytes_copied": 0, "bytes_d2h": x.nbytes,
+                                "snapshots_adopted": 1, "cow_copies": 0}
+    got = ds.read_direct()
+    assert isinstance(got, np.ndarray) and not got.flags.writeable
+    assert got.flags.f_contiguous == (major_to_minor == (1, 0))
+    with pytest.raises(ValueError):
+        got[0] = 1
+    np.testing.assert_array_equal(ds[:], want)
+    np.testing.assert_array_equal(
+        ds.slab_view((2, 0), (3, 2)).read_direct(), want[2:5])
+
+
+@pytest.mark.parametrize("end", ["delete", "donate"])
+def test_write_to_adopted_snapshot_copies_and_outlives_the_source(end):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.datamodel import transport_stats
+
+    x = jnp.arange(16, dtype=jnp.float32)
+    f = File("a.h5")
+    ds = f.create_dataset("/d", data=x)
+    kept = f.create_dataset("/kept", data=x)  # adopted, never written
+    s0 = transport_stats().snapshot()
+    ds[0] = 99.0
+    assert _stats_delta(s0) == {"bytes_copied": x.nbytes, "bytes_d2h": 0,
+                                "snapshots_adopted": 0, "cow_copies": 1}
+    want = np.arange(16, dtype=np.float32)
+    np.testing.assert_array_equal(np.asarray(x), want)  # source unchanged
+    written = want.copy()
+    written[0] = 99.0
+    if end == "delete":
+        x.delete()
+    else:
+        y = jax.jit(lambda a: a * 2.0 + 1.0, donate_argnums=0)(x)
+        np.testing.assert_array_equal(np.asarray(y), want * 2.0 + 1.0)
+    np.testing.assert_array_equal(ds[:], written)
+    np.testing.assert_array_equal(kept[:], want)
+
+
+@pytest.mark.parametrize("case", ["dtype", "host"])
+def test_snapshot_falls_back_to_a_copy(case):
+    import jax.numpy as jnp
+
+    from repro.core.datamodel import transport_stats
+
+    want = np.arange(16, dtype=np.float32)
+    s0 = transport_stats().snapshot()
+    if case == "dtype":
+        x = jnp.asarray(want)
+        ds = File("a.h5").create_dataset("/d", dtype=np.float64, data=x)
+        d2h = x.nbytes
+    else:
+        x = want.copy()
+        ds = File("a.h5").create_dataset("/d", data=x)
+        x[:] = -1.0  # the caller reuses its buffer after the write
+        d2h = 0
+    assert _stats_delta(s0) == {"bytes_copied": ds.nbytes, "bytes_d2h": d2h,
+                                "snapshots_adopted": 0, "cow_copies": 0}
+    assert ds[:].dtype == ds.dtype
+    np.testing.assert_array_equal(ds[:], want)

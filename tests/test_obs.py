@@ -819,9 +819,10 @@ def test_prefetch_spans_carry_the_handoff(tmp_path):
     assert "t" in {ev["ph"] for ev in doc["traceEvents"]}
 
 
-@pytest.mark.parametrize("data, d2h", [("device", 64), ("host", 0)],
+@pytest.mark.parametrize("data, copied, d2h", [("device", 0, 64),
+                                               ("host", 64, 0)],
                          ids=["device", "host"])
-def test_bytes_d2h_counts_device_arrays_only(data, d2h):
+def test_bytes_d2h_counts_device_arrays_only(data, copied, d2h):
     import jax.numpy as jnp
 
     from repro.core.datamodel import File, transport_stats
@@ -831,7 +832,8 @@ def test_bytes_d2h_counts_device_arrays_only(data, d2h):
     s0 = transport_stats().snapshot()
     File("x.h5").create_dataset("/g", data=arr)
     s1 = transport_stats().snapshot()
-    assert s1["bytes_copied"] - s0["bytes_copied"] == 64
+    # a device array's fetched value is adopted: no host copy is made
+    assert s1["bytes_copied"] - s0["bytes_copied"] == copied
     assert s1["bytes_d2h"] - s0["bytes_d2h"] == d2h
 
 
