@@ -32,7 +32,8 @@ Ownership rules (see DESIGN.md):
   is copied; a device array's snapshot is its fetched host value, adopted
   without a second copy when it is read-only, of the Dataset's dtype and
   contiguous in C or Fortran order (nothing can write it: the ``jax.Array``
-  is immutable).
+  is immutable).  A device array sharded over several devices is assembled
+  from its shards into a host buffer of the Dataset's own.
 * ``Dataset`` mutation goes through ``__setitem__`` / ``write_slab``; both
   materialize a private copy first if the buffer is shared or read-only
   (memmap, adopted device value).  Copies are counted in
@@ -50,6 +51,7 @@ import os
 import re
 import sys
 import threading
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from functools import lru_cache
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple, Union
@@ -94,6 +96,16 @@ def is_device_array(a: Any) -> bool:
     return jax is not None and isinstance(a, jax.Array)
 
 
+def _timed(trace: Optional[Tuple[Any, str, int, int]], name: str,
+           **args: Any) -> Any:
+    """A ``datamodel`` span of a traced write (``trace``: its recorder,
+    task, instance and step), or nothing when untraced."""
+    if trace is None:
+        return nullcontext()
+    tr, task, instance, step = trace
+    return tr.span("datamodel", name, task, instance, step=step, **args)
+
+
 def _writable_in_place(a: Any) -> bool:
     """Can this buffer be mutated where it sits?  Never true for device
     arrays (immutable) -- only for writable host ndarrays."""
@@ -111,7 +123,10 @@ class TransportStats:
     every byte fetched from a device array to the host -- a copy's fetched
     part, and the whole of a snapshot adopted with no host copy
     (``snapshots_adopted``), so it is not always a part of
-    ``bytes_copied``; ``views`` counts zero-copy dataset views handed out.
+    ``bytes_copied``; a sharded device array's snapshot is assembled from
+    its shards into a host buffer of its own (``snapshots_assembled``),
+    which counts in both; ``views`` counts zero-copy dataset views handed
+    out.
     Benchmarks reset + read these to measure the Fig. 4 overhead lever.
     """
 
@@ -121,6 +136,7 @@ class TransportStats:
         self.bytes_copied = 0
         self.bytes_d2h = 0
         self.snapshots_adopted = 0
+        self.snapshots_assembled = 0
         self.cow_copies = 0
         self.views = 0
         # M->N redistribution accounting (planned vs shipped vs whole-file):
@@ -155,15 +171,18 @@ class TransportStats:
         self.reshard_numpy = 0
 
     def record_copy(self, nbytes: int, cow: bool = False,
-                    d2h: int = 0) -> None:
+                    d2h: int = 0, assembled: bool = False) -> None:
         """One buffer copy of ``nbytes``; ``d2h`` bytes of it came from a
-        device array."""
+        device array; ``assembled``: the copy put a sharded device array's
+        shards together."""
         with self._lock:
             self.copies += 1
             self.bytes_copied += int(nbytes)
             self.bytes_d2h += int(d2h)
             if cow:
                 self.cow_copies += 1
+            if assembled:
+                self.snapshots_assembled += 1
 
     def record_d2h(self, nbytes: int) -> None:
         """A device array's snapshot adopted as its fetched host value:
@@ -217,6 +236,7 @@ class TransportStats:
                 "bytes_copied": self.bytes_copied,
                 "bytes_d2h": self.bytes_d2h,
                 "snapshots_adopted": self.snapshots_adopted,
+                "snapshots_assembled": self.snapshots_assembled,
                 "cow_copies": self.cow_copies,
                 "views": self.views,
                 "redist_planned_bytes": self.redist_planned_bytes,
@@ -237,6 +257,7 @@ class TransportStats:
         with self._lock:
             self.copies = self.bytes_copied = self.cow_copies = self.views = 0
             self.bytes_d2h = self.snapshots_adopted = 0
+            self.snapshots_assembled = 0
             self.redist_planned_bytes = self.redist_shipped_bytes = 0
             self.redist_baseline_bytes = 0
             self.redist_aligned = self.redist_slabs = 0
@@ -433,14 +454,9 @@ class Dataset:
                 # creation buys a sound invariant: every Dataset buffer is
                 # reachable only through Datasets, or is immutable (a
                 # device array's read-only fetched value, see _snapshot).
-                if trace is None:
-                    self._data = self._snapshot(arr)
-                else:
-                    tr, task, instance, step = trace
-                    with tr.span("datamodel", "datamodel.snapshot", task,
-                                 instance, step=step, bytes=int(arr.nbytes),
-                                 device=is_device_array(arr)):
-                        self._data = self._snapshot(arr, trace)
+                with _timed(trace, "datamodel.snapshot", bytes=int(arr.nbytes),
+                            device=is_device_array(arr)):
+                    self._data = self._snapshot(arr, trace)
             else:
                 # Internal zero-copy path (spill load, legacy filter): the
                 # caller guarantees nothing else writes this buffer.  A
@@ -465,23 +481,52 @@ class Dataset:
         keeps narrow-minor arrays such as (N, 2) column-major, so their
         fetched value is Fortran-ordered, and a C-ordered copy of it would
         be a transpose.  Otherwise, and for every host array, the value is
-        copied into a fresh C-ordered buffer."""
+        copied into a fresh C-ordered buffer.
+
+        A device array sharded over several devices is assembled from its
+        shards (``_assemble``) instead of fetched whole."""
         d2h = 0
         if is_device_array(arr):
+            if (not arr.sharding.is_fully_replicated
+                    and arr.is_fully_addressable):
+                return self._assemble(arr, trace)
             d2h = int(arr.nbytes)
-            if trace is None:
+            with _timed(trace, "datamodel.d2h", bytes=d2h):
                 arr = np.asarray(arr)
-            else:
-                tr, task, instance, step = trace
-                with tr.span("datamodel", "datamodel.d2h", task, instance,
-                             step=step, bytes=d2h):
-                    arr = np.asarray(arr)
             if (not arr.flags.writeable and arr.dtype == self.dtype
                     and arr.flags.forc):
                 _TRANSPORT_STATS.record_d2h(d2h)
                 return arr
         out = np.array(arr, dtype=self.dtype, order="C")
         _TRANSPORT_STATS.record_copy(out.nbytes, d2h=d2h)
+        return out
+
+    def _assemble(self, arr: Any,
+                  trace: Optional[Tuple[Any, str, int, int]]) -> np.ndarray:
+        """A sharded device array's snapshot, put together on the host.
+
+        ``np.asarray`` of such an array fetches every shard and copies each
+        into one new host buffer: a host copy of the whole array that a
+        timing of the fetch would take for transfer.  This does the same
+        work in the same order, in the open: start every distinct shard's
+        (replica 0's) transfer, allocate the Dataset's own C-ordered buffer,
+        then for each shard wait for its fetched value (``datamodel.d2h``)
+        and copy it into place (``datamodel.assemble``).  The buffer is the
+        Dataset's, so the copy counts in ``bytes_copied``."""
+        shards = [s for s in arr.addressable_shards if s.replica_id == 0]
+        for s in shards:
+            s.data.copy_to_host_async()
+        out = np.empty(self.shape, dtype=self.dtype)
+        for s in shards:
+            dev = s.device.id
+            with _timed(trace, "datamodel.d2h", bytes=int(s.data.nbytes),
+                        device_id=dev):
+                block = np.asarray(s.data)
+            with _timed(trace, "datamodel.assemble", bytes=int(block.nbytes),
+                        device_id=dev):
+                out[s.index] = block
+        _TRANSPORT_STATS.record_copy(out.nbytes, d2h=int(arr.nbytes),
+                                     assembled=True)
         return out
 
     # -- copy-on-write ------------------------------------------------------

@@ -1,5 +1,11 @@
 """HDF5-style data model: tree ops, hyperslabs, container I/O, glob match."""
 
+import json
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 from hypcompat import given, settings, st
@@ -144,7 +150,8 @@ def _stats_delta(s0):
     from repro.core.datamodel import transport_stats
     s1 = transport_stats().snapshot()
     return {k: s1[k] - s0[k] for k in
-            ("bytes_copied", "bytes_d2h", "snapshots_adopted", "cow_copies")}
+            ("bytes_copied", "bytes_d2h", "snapshots_adopted",
+             "snapshots_assembled", "cow_copies")}
 
 
 @pytest.mark.parametrize("major_to_minor", [(0, 1), (1, 0)],
@@ -165,7 +172,8 @@ def test_device_snapshot_adopts_the_fetched_value(major_to_minor):
     s0 = transport_stats().snapshot()
     ds = File("a.h5").create_dataset("/d", data=x)
     assert _stats_delta(s0) == {"bytes_copied": 0, "bytes_d2h": x.nbytes,
-                                "snapshots_adopted": 1, "cow_copies": 0}
+                                "snapshots_adopted": 1,
+                                "snapshots_assembled": 0, "cow_copies": 0}
     got = ds.read_direct()
     assert isinstance(got, np.ndarray) and not got.flags.writeable
     assert got.flags.f_contiguous == (major_to_minor == (1, 0))
@@ -190,7 +198,8 @@ def test_write_to_adopted_snapshot_copies_and_outlives_the_source(end):
     s0 = transport_stats().snapshot()
     ds[0] = 99.0
     assert _stats_delta(s0) == {"bytes_copied": x.nbytes, "bytes_d2h": 0,
-                                "snapshots_adopted": 0, "cow_copies": 1}
+                                "snapshots_adopted": 0,
+                                "snapshots_assembled": 0, "cow_copies": 1}
     want = np.arange(16, dtype=np.float32)
     np.testing.assert_array_equal(np.asarray(x), want)  # source unchanged
     written = want.copy()
@@ -222,6 +231,120 @@ def test_snapshot_falls_back_to_a_copy(case):
         x[:] = -1.0  # the caller reuses its buffer after the write
         d2h = 0
     assert _stats_delta(s0) == {"bytes_copied": ds.nbytes, "bytes_d2h": d2h,
-                                "snapshots_adopted": 0, "cow_copies": 0}
+                                "snapshots_adopted": 0,
+                                "snapshots_assembled": 0, "cow_copies": 0}
     assert ds[:].dtype == ds.dtype
     np.testing.assert_array_equal(ds[:], want)
+
+
+# ---------------------------------------------------------------------------
+# the snapshot of a device array sharded over several devices: assembled
+# from its distinct shards into the Dataset's own buffer, in the open
+# ---------------------------------------------------------------------------
+SHARDED_SNAPSHOTS = textwrap.dedent("""
+    import json, traceback
+    import jax, numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core.datamodel import Dataset, File, transport_stats
+    from repro.obs import SpanRecorder
+
+    KEYS = ("bytes_copied", "bytes_d2h", "snapshots_adopted",
+            "snapshots_assembled", "cow_copies")
+    g = np.arange(64 * 16 * 16, dtype=np.float32).reshape(64, 16, 16)
+    devs = jax.devices()
+    line = Mesh(np.array(devs[:2]), ("x",))
+    square = Mesh(np.array(devs[:4]).reshape(2, 2), ("x", "y"))
+
+    def snap(x, trace=None):
+        s0 = transport_stats().snapshot()
+        ds = Dataset("d", x.shape, x.dtype, data=x, trace=trace)
+        s1 = transport_stats().snapshot()
+        np.testing.assert_array_equal(ds[:], g)
+        return ds, {k: s1[k] - s0[k] for k in KEYS}
+
+    def sharded_axis0():
+        x = jax.device_put(g, NamedSharding(line, P("x")))
+        _, d = snap(x)
+        assert d == {"bytes_copied": g.nbytes, "bytes_d2h": g.nbytes,
+                     "snapshots_adopted": 0, "snapshots_assembled": 1,
+                     "cow_copies": 0}, d
+
+    def sharded_traced():
+        x = jax.device_put(g, NamedSharding(line, P("x")))
+        tr = SpanRecorder()
+        snap(x, trace=(tr, "nyx", 0, 3))
+        spans = [s for s in tr.spans() if s["ph"] == "X"]
+        top = [s for s in spans if s["name"] == "datamodel.snapshot"]
+        assert len(top) == 1 and top[0]["parent"] is None, top
+        for name in ("datamodel.d2h", "datamodel.assemble"):
+            got = [s for s in spans if s["name"] == name]
+            assert len(got) == 2, (name, got)
+            for s in got:
+                assert s["cat"] == "datamodel" and s["step"] == 3, s
+                assert s["parent"] == top[0]["id"], s
+                assert s["args"]["bytes"] == g.nbytes // 2, s
+            assert {s["args"]["device_id"] for s in got} == {
+                devs[0].id, devs[1].id}, got
+        assert len(spans) == 5, spans
+
+    def replicated():
+        x = jax.device_put(g, NamedSharding(line, P()))
+        _, d = snap(x)
+        assert d == {"bytes_copied": 0, "bytes_d2h": g.nbytes,
+                     "snapshots_adopted": 1, "snapshots_assembled": 0,
+                     "cow_copies": 0}, d
+
+    def mesh_2x2():
+        x = jax.device_put(g, NamedSharding(square, P("x", None)))
+        assert len(x.addressable_shards) == 4
+        _, d = snap(x)
+        assert d == {"bytes_copied": g.nbytes, "bytes_d2h": g.nbytes,
+                     "snapshots_adopted": 0, "snapshots_assembled": 1,
+                     "cow_copies": 0}, d
+
+    def write_after():
+        x = jax.device_put(g, NamedSharding(line, P("x")))
+        ds, _ = snap(x)
+        ds[0] = -1.0
+        want = g.copy()
+        want[0] = -1.0
+        np.testing.assert_array_equal(ds[:], want)
+        np.testing.assert_array_equal(np.asarray(x), g)
+        kept = File("a.h5").create_dataset("/kept", data=x)
+        np.testing.assert_array_equal(kept[:], g)
+
+    out = {}
+    for case in (sharded_axis0, sharded_traced, replicated, mesh_2x2,
+                 write_after):
+        try:
+            case()
+            out[case.__name__] = "ok"
+        except Exception:
+            out[case.__name__] = traceback.format_exc()
+    print("RESULTS " + json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def sharded_snapshots():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    out = subprocess.run([sys.executable, "-c", SHARDED_SNAPSHOTS], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = [s for s in out.stdout.splitlines() if s.startswith("RESULTS ")]
+    assert line, out.stdout[-3000:] + out.stderr[-3000:]
+    return json.loads(line[-1][len("RESULTS "):])
+
+
+@pytest.mark.parametrize("case", ["sharded_axis0", "sharded_traced",
+                                  "replicated", "mesh_2x2", "write_after"])
+def test_sharded_device_snapshot(sharded_snapshots, case):
+    """On four CPU devices: a field sharded on axis 0 is assembled from its
+    shards (one host copy, counted, with a d2h and an assemble span per
+    shard under the snapshot span); a fully replicated array is adopted as
+    before; a (2, 2) mesh fetches each distinct block once; a write through
+    the Dataset leaves the device array as it was."""
+    assert sharded_snapshots[case] == "ok", sharded_snapshots[case]
