@@ -27,6 +27,7 @@ established; the checker turns the convention into an enforced rule::
     25  scheduler      SchedulerRuntime._lock/_tick_lock, PrefetchPool cv
     30  channel.cv     the per-channel condition variable
     40  channel.sem    ResizableSemaphore cv, supervisor heartbeat lock
+    45  datamodel      a sharded snapshot's assembly (records spans, stats)
     50  leaf           mux, telemetry, stats, fault plans, driver misc
 
 Same-rank nesting is allowed only for ranks declaring it (the serve locks
@@ -57,6 +58,7 @@ RANKS: Dict[str, int] = {
     "channel.cv": 30,
     "channel.sem": 40,
     "supervisor.hb": 40,
+    "datamodel": 45,
     "leaf": 50,
 }
 

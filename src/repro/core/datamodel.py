@@ -32,8 +32,10 @@ Ownership rules (see DESIGN.md):
   is copied; a device array's snapshot is its fetched host value, adopted
   without a second copy when it is read-only, of the Dataset's dtype and
   contiguous in C or Fortran order (nothing can write it: the ``jax.Array``
-  is immutable).  A device array sharded over several devices is assembled
-  from its shards into a host buffer of the Dataset's own.
+  is immutable).  A device array sharded over several devices is kept as
+  its fetched shards (``_Shards``), each adopted by the same rule; a slab
+  inside one shard is a view of that shard, and anything else that needs
+  the whole array assembles it once per snapshot, shared by every view.
 * ``Dataset`` mutation goes through ``__setitem__`` / ``write_slab``; both
   materialize a private copy first if the buffer is shared or read-only
   (memmap, adopted device value).  Copies are counted in
@@ -123,9 +125,12 @@ class TransportStats:
     every byte fetched from a device array to the host -- a copy's fetched
     part, and the whole of a snapshot adopted with no host copy
     (``snapshots_adopted``), so it is not always a part of
-    ``bytes_copied``; a sharded device array's snapshot is assembled from
-    its shards into a host buffer of its own (``snapshots_assembled``),
-    which counts in both; ``views`` counts zero-copy dataset views handed
+    ``bytes_copied``; a sharded device array's snapshot is kept as its
+    fetched shards (``snapshots_sharded``, in ``bytes_d2h`` only), a slab
+    view inside one of them is served from it with no copy
+    (``slabs_from_shard``), and a read that needs the whole array assembles
+    it from the shards once per snapshot (``snapshots_assembled``, in
+    ``bytes_copied``); ``views`` counts zero-copy dataset views handed
     out.
     Benchmarks reset + read these to measure the Fig. 4 overhead lever.
     """
@@ -136,7 +141,9 @@ class TransportStats:
         self.bytes_copied = 0
         self.bytes_d2h = 0
         self.snapshots_adopted = 0
+        self.snapshots_sharded = 0
         self.snapshots_assembled = 0
+        self.slabs_from_shard = 0
         self.cow_copies = 0
         self.views = 0
         # M->N redistribution accounting (planned vs shipped vs whole-file):
@@ -173,7 +180,7 @@ class TransportStats:
     def record_copy(self, nbytes: int, cow: bool = False,
                     d2h: int = 0, assembled: bool = False) -> None:
         """One buffer copy of ``nbytes``; ``d2h`` bytes of it came from a
-        device array; ``assembled``: the copy put a sharded device array's
+        device array; ``assembled``: the copy put a sharded snapshot's
         shards together."""
         with self._lock:
             self.copies += 1
@@ -184,16 +191,24 @@ class TransportStats:
             if assembled:
                 self.snapshots_assembled += 1
 
-    def record_d2h(self, nbytes: int) -> None:
-        """A device array's snapshot adopted as its fetched host value:
-        ``nbytes`` crossed from the device, no host copy was made."""
+    def record_d2h(self, nbytes: int, sharded: bool = False) -> None:
+        """A device array's snapshot adopted as its fetched host value, or
+        kept as its fetched shards (``sharded``): ``nbytes`` crossed from
+        the device."""
         with self._lock:
             self.bytes_d2h += int(nbytes)
-            self.snapshots_adopted += 1
+            if sharded:
+                self.snapshots_sharded += 1
+            else:
+                self.snapshots_adopted += 1
 
-    def record_view(self) -> None:
+    def record_view(self, from_shard: bool = False) -> None:
+        """A zero-copy view handed out; ``from_shard``: a slab served from
+        one shard of a sharded snapshot."""
         with self._lock:
             self.views += 1
+            if from_shard:
+                self.slabs_from_shard += 1
 
     def record_prefetch_prepare(self, elapsed_s: float) -> None:
         with self._lock:
@@ -236,7 +251,9 @@ class TransportStats:
                 "bytes_copied": self.bytes_copied,
                 "bytes_d2h": self.bytes_d2h,
                 "snapshots_adopted": self.snapshots_adopted,
+                "snapshots_sharded": self.snapshots_sharded,
                 "snapshots_assembled": self.snapshots_assembled,
+                "slabs_from_shard": self.slabs_from_shard,
                 "cow_copies": self.cow_copies,
                 "views": self.views,
                 "redist_planned_bytes": self.redist_planned_bytes,
@@ -257,7 +274,8 @@ class TransportStats:
         with self._lock:
             self.copies = self.bytes_copied = self.cow_copies = self.views = 0
             self.bytes_d2h = self.snapshots_adopted = 0
-            self.snapshots_assembled = 0
+            self.snapshots_sharded = self.snapshots_assembled = 0
+            self.slabs_from_shard = 0
             self.redist_planned_bytes = self.redist_shipped_bytes = 0
             self.redist_baseline_bytes = 0
             self.redist_aligned = self.redist_slabs = 0
@@ -411,6 +429,97 @@ class _Share:
         self.lock = make_lock("leaf:share")
 
 
+def _adoptable(value: np.ndarray, dtype: np.dtype) -> bool:
+    """Can a fetched host value be kept as a snapshot with no copy?  Only
+    when nothing can write it and it is already what the Dataset holds."""
+    return (not value.flags.writeable and value.dtype == dtype
+            and bool(value.flags.forc))
+
+
+class _Shards:
+    """A sharded device array's snapshot, kept as its fetched shards.
+
+    ``blocks`` holds, per distinct shard, its global box (``lo``, ``hi``),
+    its read-only host value and its device id.  Every view of the snapshot
+    shares this holder (it is the Dataset's buffer, under the same
+    ``_Share``), so the CoW rules hold unchanged: nothing writes it, and a
+    write through a Dataset first takes a private copy.  ``slab`` serves a
+    box that lies inside one shard as a view of that shard; ``whole``
+    assembles the array for any other read, once per snapshot under
+    ``lock``, and every later reader reuses the result."""
+
+    __slots__ = ("shape", "dtype", "blocks", "trace", "lock", "_whole")
+
+    def __init__(self, arr: Any, dtype: np.dtype,
+                 trace: Optional[Tuple[Any, str, int, int]]):
+        """Fetch ``arr``'s distinct shards (replica 0's): start every
+        transfer, then wait for each under its own ``datamodel.d2h`` span
+        and adopt its value, or copy it when it cannot be adopted."""
+        self.shape = tuple(arr.shape)
+        self.dtype = dtype
+        self.trace = trace
+        self.lock = make_lock("datamodel:assemble")
+        self._whole: Optional[np.ndarray] = None
+        shards = [s for s in arr.addressable_shards if s.replica_id == 0]
+        for s in shards:
+            s.data.copy_to_host_async()
+        self.blocks = []
+        for s in shards:
+            dev = s.device.id
+            with _timed(trace, "datamodel.d2h", bytes=int(s.data.nbytes),
+                        device_id=dev):
+                value = np.asarray(s.data)
+            if not _adoptable(value, dtype):
+                value = np.array(value, dtype=dtype, order="C")
+                value.flags.writeable = False
+                _TRANSPORT_STATS.record_copy(value.nbytes)
+            box = [sl.indices(n)[:2] for sl, n in zip(s.index, self.shape)]
+            self.blocks.append((tuple(a for a, _ in box),
+                                tuple(z for _, z in box), value, dev))
+        _TRANSPORT_STATS.record_d2h(arr.nbytes, sharded=True)
+
+    def slab(self, starts: Sequence[int],
+             shape: Sequence[int]) -> Tuple[np.ndarray, bool]:
+        """The box as a view of the one shard that holds all of it, else
+        of the assembled array; and whether it came from a shard."""
+        stops = [s + n for s, n in zip(starts, shape)]
+        for lo, hi, value, _ in self.blocks:
+            if all(l <= s and z <= h
+                   for s, z, l, h in zip(starts, stops, lo, hi)):
+                return value[tuple(slice(s - l, z - l)
+                                   for s, z, l in zip(starts, stops, lo))], True
+        return self.whole()[tuple(slice(s, z)
+                                  for s, z in zip(starts, stops))], False
+
+    def _fill(self, out: np.ndarray) -> np.ndarray:
+        for lo, hi, value, dev in self.blocks:
+            with _timed(self.trace, "datamodel.assemble",
+                        bytes=int(value.nbytes), device_id=dev):
+                out[tuple(slice(l, h) for l, h in zip(lo, hi))] = value
+        return out
+
+    def whole(self) -> np.ndarray:
+        """The assembled array, read-only, put together by the first
+        caller and shared by all."""
+        with self.lock:
+            if self._whole is None:
+                whole = self._fill(np.empty(self.shape, dtype=self.dtype))
+                whole.flags.writeable = False
+                self._whole = whole
+                _TRANSPORT_STATS.record_copy(whole.nbytes, assembled=True)
+            return self._whole
+
+    def private_copy(self) -> Tuple[np.ndarray, bool]:
+        """A writable copy of the whole array, and whether assembling it
+        was that copy: assembled straight into the copy unless a reader
+        has already assembled the snapshot."""
+        with self.lock:
+            whole = self._whole
+        if whole is not None:
+            return np.array(whole), False
+        return self._fill(np.empty(self.shape, dtype=self.dtype)), True
+
+
 class Dataset:
     """A typed n-d array leaf with attributes and hyperslab read/write.
 
@@ -469,7 +578,7 @@ class Dataset:
 
     def _snapshot(self, arr: Any,
                   trace: Optional[Tuple[Any, str, int, int]] = None
-                  ) -> np.ndarray:
+                  ) -> Union[np.ndarray, _Shards]:
         """The Dataset's own host buffer holding ``arr``.
 
         A device array is fetched to the host (``np.asarray``, timed alone as
@@ -483,50 +592,22 @@ class Dataset:
         be a transpose.  Otherwise, and for every host array, the value is
         copied into a fresh C-ordered buffer.
 
-        A device array sharded over several devices is assembled from its
-        shards (``_assemble``) instead of fetched whole."""
+        A device array sharded over several devices is kept as its fetched
+        shards (``_Shards``), each adopted by the same rule; no buffer of
+        the whole array is allocated here."""
         d2h = 0
         if is_device_array(arr):
             if (not arr.sharding.is_fully_replicated
                     and arr.is_fully_addressable):
-                return self._assemble(arr, trace)
+                return _Shards(arr, self.dtype, trace)
             d2h = int(arr.nbytes)
             with _timed(trace, "datamodel.d2h", bytes=d2h):
                 arr = np.asarray(arr)
-            if (not arr.flags.writeable and arr.dtype == self.dtype
-                    and arr.flags.forc):
+            if _adoptable(arr, self.dtype):
                 _TRANSPORT_STATS.record_d2h(d2h)
                 return arr
         out = np.array(arr, dtype=self.dtype, order="C")
         _TRANSPORT_STATS.record_copy(out.nbytes, d2h=d2h)
-        return out
-
-    def _assemble(self, arr: Any,
-                  trace: Optional[Tuple[Any, str, int, int]]) -> np.ndarray:
-        """A sharded device array's snapshot, put together on the host.
-
-        ``np.asarray`` of such an array fetches every shard and copies each
-        into one new host buffer: a host copy of the whole array that a
-        timing of the fetch would take for transfer.  This does the same
-        work in the same order, in the open: start every distinct shard's
-        (replica 0's) transfer, allocate the Dataset's own C-ordered buffer,
-        then for each shard wait for its fetched value (``datamodel.d2h``)
-        and copy it into place (``datamodel.assemble``).  The buffer is the
-        Dataset's, so the copy counts in ``bytes_copied``."""
-        shards = [s for s in arr.addressable_shards if s.replica_id == 0]
-        for s in shards:
-            s.data.copy_to_host_async()
-        out = np.empty(self.shape, dtype=self.dtype)
-        for s in shards:
-            dev = s.device.id
-            with _timed(trace, "datamodel.d2h", bytes=int(s.data.nbytes),
-                        device_id=dev):
-                block = np.asarray(s.data)
-            with _timed(trace, "datamodel.assemble", bytes=int(block.nbytes),
-                        device_id=dev):
-                out[s.index] = block
-        _TRANSPORT_STATS.record_copy(out.nbytes, d2h=int(arr.nbytes),
-                                     assembled=True)
         return out
 
     # -- copy-on-write ------------------------------------------------------
@@ -571,7 +652,9 @@ class Dataset:
         hold: the slab is read-only while shared and a first write through
         either side copies only that side's bytes (the slab copies its slab,
         not the whole buffer).  This is what a redistributing channel ships --
-        the consumer's owned box, zero bytes moved at serve time.
+        the consumer's owned box, zero bytes moved at serve time.  Over a
+        sharded snapshot the slab is a view of the one shard that holds the
+        box, or of the assembled array when no single shard does.
         """
         slc = tuple(slice(s, s + n) for s, n in zip(starts, shape))
         ds = Dataset.__new__(Dataset)
@@ -582,8 +665,11 @@ class Dataset:
         ds.parent = parent
         ds.ownership = None
         ds._share, base = self._acquire_share()
-        ds._data = base[slc]
-        _TRANSPORT_STATS.record_view()
+        if isinstance(base, _Shards):
+            ds._data, from_shard = base.slab(starts, shape)
+        else:
+            ds._data, from_shard = base[slc], False
+        _TRANSPORT_STATS.record_view(from_shard=from_shard)
         return ds
 
     @property
@@ -601,10 +687,16 @@ class Dataset:
     def _ensure_writable(self) -> None:
         """Materialize a private copy if the buffer is shared or read-only
         (memmap, device array -- device buffers are immutable, so a write
-        always lands in a private host copy)."""
+        always lands in a private host copy).  A sharded snapshot is
+        assembled straight into the private copy."""
         while True:
             share = self._share
             sched_point("Dataset._ensure_writable", key=("share", id(share)))
+            data = self._data
+            # the shards are immutable: their copy needs no share lock, and
+            # taking the holder's lock under it would invert the lock order
+            own, assembled = (data.private_copy() if isinstance(data, _Shards)
+                              else (None, False))
             with share.lock:
                 if share is not self._share:
                     continue  # a concurrent writer swapped us; re-read
@@ -617,13 +709,14 @@ class Dataset:
                 # observe the new private buffer paired with the old share
                 # (torn-capture race -- see _acquire_share).
                 d2h = is_device_array(self._data)
-                new = np.array(self._data)
+                new = np.array(self._data) if own is None else own
                 share.count -= 1
                 self._data = new
                 self._share = _Share(1)
                 break
         _TRANSPORT_STATS.record_copy(new.nbytes, cow=True,
-                                     d2h=new.nbytes if d2h else 0)
+                                     d2h=new.nbytes if d2h else 0,
+                                     assembled=assembled)
 
     # -- HDF5-ish surface ---------------------------------------------------
     @property
@@ -643,16 +736,20 @@ class Dataset:
     def read_direct(self) -> np.ndarray:
         """The backing array; a read-only alias while the buffer is shared.
 
+        A sharded snapshot is read through its assembled array.
         Device-resident buffers (jax arrays) are immutable by construction
         and are returned as-is -- callers see a ``jax.Array`` and may hand it
         straight to the pack-kernel executors without a host round-trip.
         """
-        if is_device_array(self._data):
-            return self._data
-        _race_point("Dataset.read_direct", self._data, "r")
+        data = self._data
+        if is_device_array(data):
+            return data
+        if isinstance(data, _Shards):
+            data = data.whole()
+        _race_point("Dataset.read_direct", data, "r")
         if self._is_exclusive():
             return self._data
-        alias = self._data.view()
+        alias = data.view()
         alias.flags.writeable = False
         return alias
 

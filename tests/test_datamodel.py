@@ -242,70 +242,100 @@ def test_snapshot_falls_back_to_a_copy(case):
 # from its distinct shards into the Dataset's own buffer, in the open
 # ---------------------------------------------------------------------------
 SHARDED_SNAPSHOTS = textwrap.dedent("""
-    import json, traceback
+    import json, tempfile, traceback
     import jax, numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.core.datamodel import Dataset, File, transport_stats
     from repro.obs import SpanRecorder
 
     KEYS = ("bytes_copied", "bytes_d2h", "snapshots_adopted",
-            "snapshots_assembled", "cow_copies")
+            "snapshots_sharded", "snapshots_assembled", "slabs_from_shard",
+            "cow_copies")
     g = np.arange(64 * 16 * 16, dtype=np.float32).reshape(64, 16, 16)
     devs = jax.devices()
     line = Mesh(np.array(devs[:2]), ("x",))
     square = Mesh(np.array(devs[:4]).reshape(2, 2), ("x", "y"))
+    WRITE = {"bytes_copied": 0, "bytes_d2h": g.nbytes,
+             "snapshots_adopted": 0, "snapshots_sharded": 1,
+             "snapshots_assembled": 0, "slabs_from_shard": 0,
+             "cow_copies": 0}
+    ASSEMBLE = dict({k: 0 for k in KEYS}, bytes_copied=g.nbytes,
+                    snapshots_assembled=1)
+
+    def counted(fn):
+        s0 = transport_stats().snapshot()
+        out = fn()
+        s1 = transport_stats().snapshot()
+        return out, {k: s1[k] - s0[k] for k in KEYS}
+
+    def sharded(mesh=line, spec=P("x")):
+        return jax.device_put(g, NamedSharding(mesh, spec))
+
+    def shard_value(x, row):
+        # the fetched host value of the shard holding ``row`` (replica 0):
+        # a device array keeps its fetched value, so this is the one the
+        # snapshot kept
+        s, = [s for s in x.addressable_shards if s.replica_id == 0
+              and (s.index[0].start or 0) <= row < s.index[0].stop]
+        return np.asarray(s.data)
 
     def snap(x, trace=None):
-        s0 = transport_stats().snapshot()
-        ds = Dataset("d", x.shape, x.dtype, data=x, trace=trace)
-        s1 = transport_stats().snapshot()
-        np.testing.assert_array_equal(ds[:], g)
-        return ds, {k: s1[k] - s0[k] for k in KEYS}
+        return counted(lambda: Dataset("d", x.shape, x.dtype, data=x,
+                                       trace=trace))
 
     def sharded_axis0():
-        x = jax.device_put(g, NamedSharding(line, P("x")))
-        _, d = snap(x)
-        assert d == {"bytes_copied": g.nbytes, "bytes_d2h": g.nbytes,
-                     "snapshots_adopted": 0, "snapshots_assembled": 1,
-                     "cow_copies": 0}, d
+        ds, d = snap(sharded())
+        assert d == WRITE, d
+        got, d = counted(lambda: ds[:])
+        np.testing.assert_array_equal(got, g)
+        assert d == ASSEMBLE, d
+        got, d = counted(lambda: ds[:])  # assembled once per snapshot
+        np.testing.assert_array_equal(got, g)
+        assert d == {k: 0 for k in KEYS}, d
 
     def sharded_traced():
-        x = jax.device_put(g, NamedSharding(line, P("x")))
         tr = SpanRecorder()
-        snap(x, trace=(tr, "nyx", 0, 3))
+        ds, _ = snap(sharded(), trace=(tr, "nyx", 0, 3))
         spans = [s for s in tr.spans() if s["ph"] == "X"]
         top = [s for s in spans if s["name"] == "datamodel.snapshot"]
         assert len(top) == 1 and top[0]["parent"] is None, top
-        for name in ("datamodel.d2h", "datamodel.assemble"):
-            got = [s for s in spans if s["name"] == name]
-            assert len(got) == 2, (name, got)
+        d2h = [s for s in spans if s["name"] == "datamodel.d2h"]
+        assert all(s["parent"] == top[0]["id"] for s in d2h), d2h
+        assert len(spans) == 3, spans  # no assembly at write
+        np.testing.assert_array_equal(ds[:], g)
+        spans = [s for s in tr.spans() if s["ph"] == "X"]
+        assembled = [s for s in spans if s["name"] == "datamodel.assemble"]
+        for got in (d2h, assembled):
+            assert len(got) == 2, got
             for s in got:
                 assert s["cat"] == "datamodel" and s["step"] == 3, s
-                assert s["parent"] == top[0]["id"], s
+                assert (s["task"], s["instance"]) == ("nyx", 0), s
                 assert s["args"]["bytes"] == g.nbytes // 2, s
             assert {s["args"]["device_id"] for s in got} == {
                 devs[0].id, devs[1].id}, got
         assert len(spans) == 5, spans
 
     def replicated():
-        x = jax.device_put(g, NamedSharding(line, P()))
-        _, d = snap(x)
-        assert d == {"bytes_copied": 0, "bytes_d2h": g.nbytes,
-                     "snapshots_adopted": 1, "snapshots_assembled": 0,
-                     "cow_copies": 0}, d
+        ds, d = snap(sharded(spec=P()))
+        np.testing.assert_array_equal(ds[:], g)
+        assert d == dict({k: 0 for k in KEYS}, bytes_d2h=g.nbytes,
+                         snapshots_adopted=1), d
 
     def mesh_2x2():
-        x = jax.device_put(g, NamedSharding(square, P("x", None)))
+        x = sharded(square, P("x", None))
         assert len(x.addressable_shards) == 4
-        _, d = snap(x)
-        assert d == {"bytes_copied": g.nbytes, "bytes_d2h": g.nbytes,
-                     "snapshots_adopted": 0, "snapshots_assembled": 1,
-                     "cow_copies": 0}, d
+        ds, d = snap(x)
+        assert d == WRITE, d
+        got, d = counted(lambda: ds[:])
+        np.testing.assert_array_equal(got, g)
+        assert d == ASSEMBLE, d
 
     def write_after():
-        x = jax.device_put(g, NamedSharding(line, P("x")))
+        x = sharded()
         ds, _ = snap(x)
-        ds[0] = -1.0
+        _, d = counted(lambda: ds.__setitem__(0, -1.0))
+        # assembled straight into the writer's private copy
+        assert d == dict(ASSEMBLE, cow_copies=1), d
         want = g.copy()
         want[0] = -1.0
         np.testing.assert_array_equal(ds[:], want)
@@ -313,9 +343,70 @@ SHARDED_SNAPSHOTS = textwrap.dedent("""
         kept = File("a.h5").create_dataset("/kept", data=x)
         np.testing.assert_array_equal(kept[:], g)
 
+    def slab_in_shard():
+        x = sharded()
+        ds, _ = snap(x)
+        slab, d = counted(lambda: ds.slab_view((40, 2, 0), (8, 12, 16)))
+        assert d == dict({k: 0 for k in KEYS}, slabs_from_shard=1), d
+        got = slab[:]
+        assert isinstance(slab._data, np.ndarray)
+        assert np.shares_memory(got, shard_value(x, 40))
+        assert not np.shares_memory(got, shard_value(x, 0))
+        np.testing.assert_array_equal(got, g[40:48, 2:14])
+
+    def slab_across_shards():
+        ds, _ = snap(sharded())
+        slab, d = counted(lambda: ds.slab_view((24, 0, 0), (16, 16, 16)))
+        assert d == ASSEMBLE, d
+        np.testing.assert_array_equal(slab[:], g[24:40])
+        again, d = counted(lambda: ds.slab_view((30, 0, 0), (4, 16, 16)))
+        assert d == {k: 0 for k in KEYS}, d
+        np.testing.assert_array_equal(again[:], g[30:34])
+        assert np.shares_memory(slab[:], again[:])
+
+    def views_assemble_once():
+        f = File("a.h5")
+        f.create_dataset("/d", data=sharded())
+        a, b = f.view()["/d"], f.view()["/d"].view()
+        (ra, rb), d = counted(lambda: (a[:], b[:]))
+        assert d == ASSEMBLE, d
+        assert np.shares_memory(ra, rb)
+        np.testing.assert_array_equal(rb, g)
+
+    def mesh_2x2_slab():
+        x = sharded(square, P("x", None))
+        ds, _ = snap(x)
+        slab, d = counted(lambda: ds.slab_view((32, 0, 0), (32, 16, 16)))
+        assert d == dict({k: 0 for k in KEYS}, slabs_from_shard=1), d
+        assert np.shares_memory(slab[:], shard_value(x, 32))
+        np.testing.assert_array_equal(slab[:], g[32:])
+
+    def write_after_slab():
+        x = sharded()
+        ds, _ = snap(x)
+        sibling = ds.slab_view((0, 0, 0), (32, 16, 16))
+        mine = ds.slab_view((32, 0, 0), (32, 16, 16))
+        _, d = counted(lambda: mine.__setitem__(0, -1.0))
+        # the slab copies its own rows only
+        assert d == dict({k: 0 for k in KEYS}, bytes_copied=g.nbytes // 2,
+                         cow_copies=1), d
+        assert float(mine[0, 0, 0]) == -1.0
+        np.testing.assert_array_equal(sibling[:], g[:32])
+        np.testing.assert_array_equal(ds[:], g)
+        np.testing.assert_array_equal(np.asarray(x), g)
+
+    def spill_round_trip():
+        f = File("a.h5")
+        f.create_dataset("/d", data=sharded())
+        path, d = counted(lambda: f.save(tempfile.mkdtemp()))
+        assert d == ASSEMBLE, d
+        np.testing.assert_array_equal(File.load(path)["/d"][:], g)
+
     out = {}
     for case in (sharded_axis0, sharded_traced, replicated, mesh_2x2,
-                 write_after):
+                 write_after, slab_in_shard, slab_across_shards,
+                 views_assemble_once, mesh_2x2_slab, write_after_slab,
+                 spill_round_trip):
         try:
             case()
             out[case.__name__] = "ok"
@@ -339,12 +430,18 @@ def sharded_snapshots():
     return json.loads(line[-1][len("RESULTS "):])
 
 
-@pytest.mark.parametrize("case", ["sharded_axis0", "sharded_traced",
-                                  "replicated", "mesh_2x2", "write_after"])
+@pytest.mark.parametrize("case", [
+    "sharded_axis0", "sharded_traced", "replicated", "mesh_2x2",
+    "write_after", "slab_in_shard", "slab_across_shards",
+    "views_assemble_once", "mesh_2x2_slab", "write_after_slab",
+    "spill_round_trip"])
 def test_sharded_device_snapshot(sharded_snapshots, case):
-    """On four CPU devices: a field sharded on axis 0 is assembled from its
-    shards (one host copy, counted, with a d2h and an assemble span per
-    shard under the snapshot span); a fully replicated array is adopted as
+    """On four CPU devices: a field sharded on axis 0 is kept as its fetched
+    shards, with no host copy at write (a d2h span per shard under the
+    snapshot span); a slab inside one shard is a view of that shard; a read
+    of the whole, a slab across shards, a write or a spill assembles the
+    array once per snapshot (an assemble span per shard, on the write's
+    step), shared by every view; a fully replicated array is adopted as
     before; a (2, 2) mesh fetches each distinct block once; a write through
-    the Dataset leaves the device array as it was."""
+    the Dataset leaves the device array and sibling slabs as they were."""
     assert sharded_snapshots[case] == "ok", sharded_snapshots[case]

@@ -1,5 +1,10 @@
 """M->N redistribution planner/executors: property-based to the byte."""
 
+import json
+import os
+import subprocess
+import sys
+import textwrap
 import threading
 import time
 
@@ -792,3 +797,87 @@ def test_prefetch_prepare_error_unblocks_producer():
     # here forever; the failure containment makes offer a no-op instead
     assert ch.offer(f) is False
     assert ch.get(timeout=5) is None         # done, not hung
+
+
+SHARDED_PRODUCER = textwrap.dedent("""
+    import json, threading
+    import jax, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import Wilkins, h5
+    from repro.core.datamodel import reset_transport_stats, transport_stats
+
+    YAML = '''
+    tasks:
+      - func: producer
+        nprocs: 2
+        outports:
+          - filename: o.h5
+            dsets: [{name: /g, memory: 1}]
+      - func: consumer
+        nprocs: 1
+        taskCount: 2
+        inports:
+          - filename: o.h5
+            redistribute: {axis: 0}
+            dsets: [{name: /g, memory: 1}]
+    '''
+    steps = 3
+    g = np.arange(64 * 8 * 16, dtype=np.float32).reshape(64, 8, 16)
+    shards, got = {}, []
+    lock = threading.Lock()
+
+    def producer(comm):
+        mesh = comm.mesh()
+        for t in range(steps):
+            x = jax.device_put(g + t, NamedSharding(mesh, P(mesh.axis_names[0])))
+            with lock:  # a device array keeps its fetched host value
+                shards[t] = {s.index[0].start or 0: np.asarray(s.data)
+                             for s in x.addressable_shards}
+            with h5.File("o.h5", "w") as f:
+                f.create_dataset("/g", data=x).attrs["step"] = t
+
+    def consumer(comm):
+        while True:
+            f = h5.File("o.h5", "r")
+            if f is None:
+                return
+            d = f["/g"]
+            t, row = int(d.attrs["step"]), d.attrs["redist_box_starts"][0]
+            arr = d[:]
+            with lock:
+                got.append({
+                    "instance": comm.instance, "step": t, "row": row,
+                    "exact": bool(np.array_equal(arr, (g + t)[row:row + 32])),
+                    "shares": bool(np.shares_memory(arr, shards[t][row]))})
+
+    reset_transport_stats()
+    Wilkins(YAML, {"producer": producer, "consumer": consumer},
+            devices=jax.devices()[:4]).run(timeout=120)
+    print("RESULT " + json.dumps({"got": got,
+                                  "stats": transport_stats().snapshot()}))
+""")
+
+
+def test_sharded_producer_ships_each_instance_its_own_shard():
+    """On four CPU devices, a producer whose field is sharded on axis 0 over
+    its two devices feeds a ``redistribute: {axis: 0}`` port of two
+    instances: each instance's slab is a view of its own shard's fetched
+    value, exact, and the run copies no byte on the host."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    p = subprocess.run([sys.executable, "-c", SHARDED_PRODUCER], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = [s for s in p.stdout.splitlines() if s.startswith("RESULT ")]
+    assert line, p.stdout[-3000:] + p.stderr[-3000:]
+    res = json.loads(line[-1][len("RESULT "):])
+    got = sorted((d["instance"], d["step"]) for d in res["got"])
+    assert got == [(i, t) for i in range(2) for t in range(3)]
+    for d in res["got"]:
+        assert d["row"] == 32 * d["instance"] and d["exact"] and d["shares"], d
+    s = res["stats"]
+    assert s["bytes_copied"] == 0 and s["snapshots_assembled"] == 0, s
+    assert s["snapshots_sharded"] == 3 and s["slabs_from_shard"] == 6, s
+    assert s["bytes_d2h"] == 3 * 64 * 8 * 16 * 4, s
