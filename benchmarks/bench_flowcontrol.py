@@ -19,6 +19,8 @@ from __future__ import annotations
 
 import csv
 import os
+import shutil
+import tempfile
 import time
 from typing import Any, Dict
 
@@ -89,7 +91,7 @@ tasks:
     nprocs: 2
     outports:
       - filename: fast.h5
-        dsets: [{{name: /grid, memory: 1}}]
+        dsets: [{{name: /grid, file: 1, memory: 0}}]
   - func: cons_slow
     nprocs: 2
     inports:
@@ -97,12 +99,12 @@ tasks:
         redistribute: 1
         queue_depth: 4
         {hot}
-        dsets: [{{name: /grid, memory: 1}}]
+        dsets: [{{name: /grid, file: 1, memory: 0}}]
   - func: prod_slow
     nprocs: 2
     outports:
       - filename: slow.h5
-        dsets: [{{name: /grid, memory: 1}}]
+        dsets: [{{name: /grid, file: 1, memory: 0}}]
   - func: cons_fast
     nprocs: 2
     inports:
@@ -110,15 +112,16 @@ tasks:
         redistribute: 1
         queue_depth: 2
         prefetch: 1
-        dsets: [{{name: /grid, memory: 1}}]
+        dsets: [{{name: /grid, file: 1, memory: 0}}]
 """
 
 
 def _run_disparate(adaptive: bool, mib_per_step: float, steps: int
                    ) -> Dict[str, Any]:
     """One disparate-rate run; returns per-edge blocked/hit counters and the
-    telemetry round-trip check.  ``zero_copy=False`` makes payload prep do a
-    real slab copy -- the serve-side cost the depth autotuner must hide."""
+    telemetry round-trip check.  The edges spill through ``file: 1``, so
+    payload prep writes each slab to disk -- the serve-side cost the depth
+    autotuner must hide."""
     n = int(mib_per_step * MIB // 8)
     payload = np.arange(n, dtype=np.float64)
     own = BlockOwnership()
@@ -150,14 +153,18 @@ def _run_disparate(adaptive: bool, mib_per_step: float, steps: int
                 return
             _ = float(f["/grid"][0])
 
-    w = Wilkins(_disparate_yaml(adaptive),
-                {"prod_fast": prod_fast, "cons_slow": cons_slow,
-                 "prod_slow": prod_slow, "cons_fast": cons_fast},
-                zero_copy=False)
-    reset_transport_stats()
-    t0 = time.monotonic()
-    rep = w.run(timeout=300)
-    wall = time.monotonic() - t0
+    spill = tempfile.mkdtemp(prefix="wilkins_bench_disparate_")
+    try:
+        w = Wilkins(_disparate_yaml(adaptive),
+                    {"prod_fast": prod_fast, "cons_slow": cons_slow,
+                     "prod_slow": prod_slow, "cons_fast": cons_fast},
+                    spill_dir=spill)
+        reset_transport_stats()
+        t0 = time.monotonic()
+        rep = w.run(timeout=300)
+        wall = time.monotonic() - t0
+    finally:
+        shutil.rmtree(spill, ignore_errors=True)
 
     def edge_sum(task, field):
         return sum(getattr(c.stats, field) for c in w.channels

@@ -25,6 +25,8 @@ as ``BENCH_redistribute.json`` via ``common.write_json``.
 from __future__ import annotations
 
 import argparse
+import shutil
+import tempfile
 import time
 from typing import Any, Dict
 
@@ -42,8 +44,9 @@ MIB = 1 << 20
 
 
 def _mxn_yaml(n_prod: int, n_cons: int, cons_ranks: int,
-              redistribute: bool, extra: str = "") -> str:
+              redistribute: bool, extra: str = "", spill: bool = False) -> str:
     redist = "redistribute: 1" if redistribute else "redistribute: 0"
+    mode = "file: 1, memory: 0" if spill else "memory: 1"
     return f"""
 tasks:
   - func: producer
@@ -51,7 +54,7 @@ tasks:
     nprocs: {n_prod}
     outports:
       - filename: o.h5
-        dsets: [{{name: /grid, memory: 1}}]
+        dsets: [{{name: /grid, {mode}}}]
   - func: consumer
     taskCount: {n_cons}
     nprocs: {cons_ranks}
@@ -59,7 +62,7 @@ tasks:
       - filename: o.h5
         {redist}
         {extra}
-        dsets: [{{name: /grid, memory: 1}}]
+        dsets: [{{name: /grid, {mode}}}]
 """
 
 
@@ -235,9 +238,9 @@ def _run_prefetch(prefetch_on: bool, mib_per_step: float, steps: int,
                   compute_iters: int = 3) -> Dict[str, Any]:
     """One 4->2 run with reshard-consuming compute; prefetch on or off.
 
-    Runs ``zero_copy=False`` so payload preparation does real slab copies
-    (the serve-side work the executor is supposed to hide); the consumer
-    reshards its received slab onto its logical ranks with
+    Spills through ``file: 1`` so payload preparation writes each slab to
+    disk (the serve-side work the executor is supposed to hide); the
+    consumer reshards its received slab onto its logical ranks with
     ``TaskComm.reshard`` and computes on each block.
     """
     from repro.core import comm as comm_mod
@@ -264,13 +267,17 @@ def _run_prefetch(prefetch_on: bool, mib_per_step: float, steps: int,
                     _ = np.tanh(b).sum()
 
     knob = "prefetch: 1" if prefetch_on else "prefetch: 0"
-    w = Wilkins(_mxn_yaml(n_prod, n_cons, 2, True, extra=knob),
-                {"producer": producer, "consumer": consumer},
-                zero_copy=False)
-    reset_plan_cache()
-    reset_transport_stats()
-    with Timer() as t:
-        rep = w.run(timeout=600)
+    spill = tempfile.mkdtemp(prefix="wilkins_bench_prefetch_")
+    try:
+        w = Wilkins(_mxn_yaml(n_prod, n_cons, 2, True, extra=knob, spill=True),
+                    {"producer": producer, "consumer": consumer},
+                    spill_dir=spill)
+        reset_plan_cache()
+        reset_transport_stats()
+        with Timer() as t:
+            rep = w.run(timeout=600)
+    finally:
+        shutil.rmtree(spill, ignore_errors=True)
     s = transport_stats().snapshot()
     return {
         "prefetch": prefetch_on,

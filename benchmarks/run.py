@@ -7,7 +7,6 @@
   ensembles    -> paper Figs. 7/8/9 (fan-out / fan-in / NxN)
   nucleation   -> paper Fig. 10 (materials-science NxN ensemble, nwriters=1)
   cosmo        -> paper Table 3 (Nyx+Reeber, custom actions + io_freq sweep)
-  transport    -> zero-copy fast path (CoW fan-out, mmap spill, queue_depth)
   redistribute -> M->N planned transport (plan cache, slab shipping, aligned
                   fast path, Pallas pack executor)
   recovery     -> fault-tolerant execution (mid-run crash, checkpointed
@@ -24,9 +23,8 @@
 ``--smoke`` is the tier-1 entry point: it first runs the pre-run analyzer
 self-check (``repro.analysis`` over every example workflow plus the lock-
 discipline AST lint over ``src/repro`` -- any error-severity finding fails
-the gate), then the pytest suite, a small
-transport bench, a small redistribution bench, and the scheduler bench, and
-fails if any fails (gates: fan-out copy reduction >= 2x, M->N bytes-shipped
+the gate), then the pytest suite, a small redistribution bench, and the
+scheduler bench, and fails if any fails (gates: M->N bytes-shipped
 reduction >= 2x, plan-cache hit rate >= 0.9, zero aligned-path copies,
 prefetch overlap >= 0.30, a byte-exact 3-D reshard on the flattened
 pack-kernel path, the autotuned disparate-rate run's consumer blocked_s at
@@ -38,9 +36,9 @@ buckets summing to each instance's window).
 ``WILKINS_SMOKE_SKIP_PYTEST=1`` skips the pytest stage (CI runs the suite
 as its own fast/slow job steps).
 
-Every benchmark prints ``name,value,unit,derived`` CSV rows; the transport
-and redistribution benches additionally write machine-readable
-``BENCH_transport.json`` / ``BENCH_redistribute.json``.
+Every benchmark prints ``name,value,unit,derived`` CSV rows; the
+redistribution bench additionally writes machine-readable
+``BENCH_redistribute.json``.
 """
 
 from __future__ import annotations
@@ -53,14 +51,14 @@ import time
 import traceback
 
 SUITES = ("overhead", "flowcontrol", "ensembles", "nucleation", "cosmo",
-          "transport", "redistribute", "recovery", "rescale", "explore",
-          "obs", "roofline")
+          "redistribute", "recovery", "rescale", "explore", "obs",
+          "roofline")
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _smoke() -> int:
-    """Tier-1 gate: pytest suite + transport bench at smoke sizes.
+    """Tier-1 gate: pytest suite + the gated benches at smoke sizes.
 
     Set ``WILKINS_SMOKE_SKIP_PYTEST=1`` to skip the pytest stage (CI runs
     the suite as its own job step right before the smoke benches, split
@@ -95,13 +93,6 @@ def _smoke() -> int:
         if rc != 0:
             print("==== smoke: pytest FAILED ====", flush=True)
             return rc
-    print("==== smoke: bench_transport ====", flush=True)
-    from . import bench_transport
-    results = bench_transport.main(smoke=True)
-    ratio = results["fanout"]["copy_reduction_x"]
-    print(f"==== smoke: copy_reduction={ratio:.1f}x ====", flush=True)
-    if ratio < 2.0:
-        return 1
     print("==== smoke: bench_redistribute ====", flush=True)
     from . import bench_redistribute
     rr = bench_redistribute.main(smoke=True)
@@ -184,8 +175,8 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", choices=SUITES, default=None)
     ap.add_argument("--smoke", action="store_true",
-                    help="run the tier-1 pytest suite + a quick transport "
-                         "bench and exit")
+                    help="run the tier-1 pytest suite + the gated smoke "
+                         "benches and exit")
     args = ap.parse_args()
     if args.smoke:
         return _smoke()

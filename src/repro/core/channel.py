@@ -20,8 +20,7 @@ Flow control (paper §3.6), selected by ``io_freq``:
 Transport fast path: ``filter_file`` ships copy-on-write dataset *views*
 (``Dataset.view``), so a fan-out of N channels serves ONE filtered payload --
 the per-dataset ``_Share`` refcount tracks the sharing and the first consumer
-write materializes a private copy.  Pass ``zero_copy=False`` to get the old
-materialize-per-channel behaviour (the benchmark's legacy baseline).
+write materializes a private copy.
 
 The channel also implements the producer-query protocol of §3.5.1: when the
 producer finishes it marks the channel done; a consumer ``get()`` after that
@@ -44,8 +43,6 @@ from collections import deque
 from concurrent.futures import CancelledError, Future
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple, Union
-
-import numpy as np
 
 from ..analysis.lockcheck import (check_blocking, hb_consume, hb_publish,
                                   make_condition, make_lock, sched_point)
@@ -164,7 +161,7 @@ class PrefetchPool:
     """Shared executor for asynchronous payload preparation (slab prefetch).
 
     Channels with a RedistSpec enqueue a *future* of the filtered payload, so
-    slab construction / eager copies / spill writes overlap with both the
+    slab construction and spill writes overlap with both the
     producer's rendezvous wait and the consumer's compute on the previous
     step.  Unlike ``concurrent.futures.ThreadPoolExecutor`` (whose non-daemon
     workers are joined at interpreter exit -- a payload prep stuck in I/O
@@ -410,7 +407,6 @@ class Channel:
         spill_dir: Optional[str] = None,
         record_events: bool = False,
         queue_depth: int = 1,
-        zero_copy: bool = True,
         redistribute: Optional[RedistSpec] = None,
         prefetch: Optional[Union[bool, int]] = None,
         events_maxlen: int = EVENTS_MAXLEN,
@@ -430,7 +426,6 @@ class Channel:
         if queue_depth < 1:
             raise ValueError(f"queue_depth must be >= 1, got {queue_depth}")
         self.queue_depth = int(queue_depth)
-        self.zero_copy = bool(zero_copy)
         self.redistribute = redistribute
         # Async payload preparation: ``prefetch`` is the PER-EDGE depth --
         # the max number of in-flight preps on this channel (0 = synchronous
@@ -892,28 +887,18 @@ class Channel:
     def filter_file(self, f: File) -> File:
         """Data-centric selection: ship only the datasets this port asked for.
 
-        Zero-copy mode grafts CoW views; a port with declared M->N ownership
-        (``redistribute``) consults the plan cache and ships only this
-        consumer instance's owned slab of each dataset.  Legacy mode
-        materializes a private copy per dataset (the seed's per-channel
-        deep-copy behaviour).
+        Each dataset is grafted as a CoW view; a port with declared M->N
+        ownership (``redistribute``) consults the plan cache and ships only
+        this consumer instance's owned slab of each dataset.
         """
         out = File(f.filename)
         out.attrs.update(f.attrs)
         for ds in f.visit_datasets():
             if any(m.matches(ds.path) for m in self._dset_matchers):
                 if self.redistribute is not None:
-                    # the slab contract holds in legacy mode too (the copy is
-                    # eager there instead of CoW-deferred)
                     self._attach_redistributed(out, ds)
-                elif self.zero_copy:
-                    out.attach_view(ds)
                 else:
-                    buf = np.array(ds.read_direct())  # eager materialization
-                    transport_stats().record_copy(buf.nbytes)
-                    nd = out.create_dataset(ds.path, data=buf, copy=False)
-                    nd.attrs.update(ds.attrs)
-                    nd.ownership = ds.ownership
+                    out.attach_view(ds)
         return out
 
     def _attach_redistributed(self, out: File, ds) -> None:
@@ -931,10 +916,6 @@ class Channel:
         * otherwise the instance's union box ships as a CoW ``slab_view``
           (still zero copies in-process; the slab's nbytes is what would
           cross the wire) with per-rank dst blocks as its ownership map.
-
-        Legacy (``zero_copy=False``) channels honor the same contract with
-        eager copies: the consumer still receives only its owned slab, with
-        the same attrs and ownership map.
         """
         spec = self.redistribute
         shape = ds.shape
@@ -956,26 +937,13 @@ class Channel:
 
         stats = transport_stats()
         if plan.aligned and spec.nslots == 1:
-            if self.zero_copy:
-                v = out.attach_view(ds)
-            else:
-                buf = np.array(ds.read_direct())
-                stats.record_copy(buf.nbytes)
-                v = out.create_dataset(ds.path, data=buf, copy=False)
-                v.attrs.update(ds.attrs)
+            v = out.attach_view(ds)
             v.ownership = own
             stats.record_redistribution(planned, ds.nbytes, ds.nbytes,
                                         aligned=True)
             return
         box_starts, box_shape = slot_boxes[spec.slot]
-        if self.zero_copy:
-            v = out.attach_slab(ds, box_starts, box_shape)
-        else:
-            slc = tuple(slice(s, s + n) for s, n in zip(box_starts, box_shape))
-            buf = np.array(ds.read_direct()[slc])
-            stats.record_copy(buf.nbytes)
-            v = out.create_dataset(ds.path, data=buf, copy=False)
-            v.attrs.update(ds.attrs)
+        v = out.attach_slab(ds, box_starts, box_shape)
         v.ownership = own
         v.attrs["redist_global_shape"] = list(shape)
         v.attrs["redist_box_starts"] = list(box_starts)
@@ -1201,16 +1169,13 @@ class Channel:
         cache get/set are GIL-atomic and a lost race merely duplicates the
         (cheap, CoW) filter work for one step, never corrupts a payload.
         """
-        if self.zero_copy:
-            key = (tuple(self.dset_patterns), self.redistribute)
-            base = cache.get(key) if cache is not None else None
-            if base is None:
-                base = self.filter_file(f)
-                if cache is not None:
-                    cache[key] = base
-            sub = base.view()  # per-channel tree, shared buffers
-        else:
-            sub = self.filter_file(f)
+        key = (tuple(self.dset_patterns), self.redistribute)
+        base = cache.get(key) if cache is not None else None
+        if base is None:
+            base = self.filter_file(f)
+            if cache is not None:
+                cache[key] = base
+        sub = base.view()  # per-channel tree, shared buffers
         payload_bytes = sub.total_bytes()
         if self.mode == "file":
             # Spill through "disk" -- the paper's ``file: 1`` transport path.
@@ -1343,7 +1308,7 @@ class Channel:
                           bytes=payload_bytes)
             kind, payload = inner
         if kind == "file":
-            f = File.load(payload, mmap=True)
+            f = File.load(payload)
             try:
                 os.unlink(payload)  # np.memmap keeps the mapping alive (POSIX)
             except OSError:

@@ -29,13 +29,14 @@ concern; patterns are compiled once to regexes and LRU-cached (see
 Ownership rules (see DESIGN.md):
 
 * ``create_dataset(data=arr)`` snapshots the caller's array.  A host array
-  is copied; a device array's snapshot is its fetched host value, adopted
-  without a second copy when it is read-only, of the Dataset's dtype and
-  contiguous in C or Fortran order (nothing can write it: the ``jax.Array``
-  is immutable).  A device array sharded over several devices is kept as
-  its fetched shards (``_Shards``), each adopted by the same rule; a slab
-  inside one shard is a view of that shard, and anything else that needs
-  the whole array assembles it once per snapshot, shared by every view.
+  is copied; a device array's snapshot is kept as its fetched blocks
+  (``_DeviceSnapshot``), one per distinct shard, each adopted without a
+  second copy when it is read-only, of the Dataset's dtype and contiguous in
+  C or Fortran order (nothing can write it: the ``jax.Array`` is immutable).
+  A one-device or fully replicated array is the one-block case, whose block
+  is the whole array.  A slab inside one block is a view of that block, and
+  anything else that needs the whole of a sharded array assembles it once
+  per snapshot, shared by every view.
 * ``Dataset`` mutation goes through ``__setitem__`` / ``write_slab``; both
   materialize a private copy first if the buffer is shared or read-only
   (memmap, adopted device value).  Copies are counted in
@@ -47,7 +48,6 @@ Ownership rules (see DESIGN.md):
 from __future__ import annotations
 
 import fnmatch
-import io
 import json
 import os
 import re
@@ -120,16 +120,17 @@ def _writable_in_place(a: Any) -> bool:
 class TransportStats:
     """Process-wide counters for data-movement work in the transport path.
 
-    ``bytes_copied`` counts actual buffer materializations (eager copies in
-    the legacy path, deferred CoW copies in the fast path); ``bytes_d2h``
-    every byte fetched from a device array to the host -- a copy's fetched
-    part, and the whole of a snapshot adopted with no host copy
-    (``snapshots_adopted``), so it is not always a part of
-    ``bytes_copied``; a sharded device array's snapshot is kept as its
-    fetched shards (``snapshots_sharded``, in ``bytes_d2h`` only), a slab
-    view inside one of them is served from it with no copy
-    (``slabs_from_shard``), and a read that needs the whole array assembles
-    it from the shards once per snapshot (``snapshots_assembled``, in
+    ``bytes_copied`` counts actual buffer materializations (snapshot
+    copies of host arrays, deferred CoW copies, assemblies); ``bytes_d2h``
+    every byte fetched from a device array to the host -- the whole of each
+    device snapshot, and a CoW copy of a device buffer -- so it is not
+    always a part of ``bytes_copied``.  A device snapshot of one block
+    adopted with no host copy counts in ``snapshots_adopted``, one of
+    several blocks (a sharded array) in ``snapshots_sharded``; a block that
+    could not be adopted is copied (``bytes_copied``).  A slab view inside
+    one block of a sharded snapshot is served from it with no copy
+    (``slabs_from_shard``), and a read that needs the whole sharded array
+    assembles it once per snapshot (``snapshots_assembled``, in
     ``bytes_copied``); ``views`` counts zero-copy dataset views handed
     out.
     Benchmarks reset + read these to measure the Fig. 4 overhead lever.
@@ -191,15 +192,15 @@ class TransportStats:
             if assembled:
                 self.snapshots_assembled += 1
 
-    def record_d2h(self, nbytes: int, sharded: bool = False) -> None:
-        """A device array's snapshot adopted as its fetched host value, or
-        kept as its fetched shards (``sharded``): ``nbytes`` crossed from
-        the device."""
+    def record_d2h(self, nbytes: int, blocks: int, adopted: bool) -> None:
+        """A device array's snapshot: ``nbytes`` crossed from the device
+        into ``blocks`` host blocks, all of them ``adopted`` with no host
+        copy or not."""
         with self._lock:
             self.bytes_d2h += int(nbytes)
-            if sharded:
+            if blocks > 1:
                 self.snapshots_sharded += 1
-            else:
+            elif adopted:
                 self.snapshots_adopted += 1
 
     def record_view(self, from_shard: bool = False) -> None:
@@ -431,39 +432,50 @@ class _Share:
 
 def _adoptable(value: np.ndarray, dtype: np.dtype) -> bool:
     """Can a fetched host value be kept as a snapshot with no copy?  Only
-    when nothing can write it and it is already what the Dataset holds."""
+    when nothing can write it and it is already what the Dataset holds.
+    Either order will do: the order is the device layout's, and a TPU keeps
+    narrow-minor arrays such as (N, 2) column-major, so a C-ordered copy of
+    their Fortran-ordered value would be a transpose."""
     return (not value.flags.writeable and value.dtype == dtype
             and bool(value.flags.forc))
 
 
-class _Shards:
-    """A sharded device array's snapshot, kept as its fetched shards.
+class _DeviceSnapshot:
+    """A device array's snapshot, kept as its fetched blocks.
 
-    ``blocks`` holds, per distinct shard, its global box (``lo``, ``hi``),
-    its read-only host value and its device id.  Every view of the snapshot
-    shares this holder (it is the Dataset's buffer, under the same
-    ``_Share``), so the CoW rules hold unchanged: nothing writes it, and a
-    write through a Dataset first takes a private copy.  ``slab`` serves a
-    box that lies inside one shard as a view of that shard; ``whole``
-    assembles the array for any other read, once per snapshot under
+    ``blocks`` holds, per distinct shard (replica 0's), its global box
+    (``lo``, ``hi``), its read-only host value and its device id; a
+    one-device or fully replicated array is the one-block case.  A block
+    is adopted as fetched when it can be (``_adoptable``): the
+    ``jax.Array`` is immutable and JAX donates no buffer with an external
+    reference, so nothing can write it.  Every view of the snapshot shares
+    this holder (it is the Dataset's buffer, under the same ``_Share``), so
+    the CoW rules hold unchanged: a write through a Dataset first takes a
+    private copy.  ``slab`` serves a box that lies inside one block as a
+    view of that block; ``whole`` is the one block, or assembles the blocks
+    of a sharded array for any other read, once per snapshot under
     ``lock``, and every later reader reuses the result."""
 
     __slots__ = ("shape", "dtype", "blocks", "trace", "lock", "_whole")
 
     def __init__(self, arr: Any, dtype: np.dtype,
                  trace: Optional[Tuple[Any, str, int, int]]):
-        """Fetch ``arr``'s distinct shards (replica 0's): start every
-        transfer, then wait for each under its own ``datamodel.d2h`` span
-        and adopt its value, or copy it when it cannot be adopted."""
+        """Fetch ``arr``'s distinct shards: start every transfer, then wait
+        for each under its own ``datamodel.d2h`` span and adopt its value,
+        or copy it into a C-ordered block when it cannot be adopted."""
+        if not arr.is_fully_addressable:
+            raise ValueError(
+                "cannot snapshot a jax.Array with shards on devices of "
+                "other processes; write its addressable part instead")
         self.shape = tuple(arr.shape)
         self.dtype = dtype
         self.trace = trace
         self.lock = make_lock("datamodel:assemble")
-        self._whole: Optional[np.ndarray] = None
         shards = [s for s in arr.addressable_shards if s.replica_id == 0]
         for s in shards:
             s.data.copy_to_host_async()
         self.blocks = []
+        adopted = True
         for s in shards:
             dev = s.device.id
             with _timed(trace, "datamodel.d2h", bytes=int(s.data.nbytes),
@@ -473,21 +485,27 @@ class _Shards:
                 value = np.array(value, dtype=dtype, order="C")
                 value.flags.writeable = False
                 _TRANSPORT_STATS.record_copy(value.nbytes)
+                adopted = False
             box = [sl.indices(n)[:2] for sl, n in zip(s.index, self.shape)]
             self.blocks.append((tuple(a for a, _ in box),
                                 tuple(z for _, z in box), value, dev))
-        _TRANSPORT_STATS.record_d2h(arr.nbytes, sharded=True)
+        # one block covers the whole array: it is never assembled
+        self._whole: Optional[np.ndarray] = (
+            self.blocks[0][2] if len(self.blocks) == 1 else None)
+        _TRANSPORT_STATS.record_d2h(arr.nbytes, len(self.blocks), adopted)
 
     def slab(self, starts: Sequence[int],
              shape: Sequence[int]) -> Tuple[np.ndarray, bool]:
-        """The box as a view of the one shard that holds all of it, else
-        of the assembled array; and whether it came from a shard."""
+        """The box as a view of the one block that holds all of it, else
+        of the assembled array; and whether it came from one shard of a
+        sharded array."""
         stops = [s + n for s, n in zip(starts, shape)]
         for lo, hi, value, _ in self.blocks:
             if all(l <= s and z <= h
                    for s, z, l, h in zip(starts, stops, lo, hi)):
-                return value[tuple(slice(s - l, z - l)
-                                   for s, z, l in zip(starts, stops, lo))], True
+                return (value[tuple(slice(s - l, z - l)
+                                    for s, z, l in zip(starts, stops, lo))],
+                        len(self.blocks) > 1)
         return self.whole()[tuple(slice(s, z)
                                   for s, z in zip(starts, stops))], False
 
@@ -499,8 +517,8 @@ class _Shards:
         return out
 
     def whole(self) -> np.ndarray:
-        """The assembled array, read-only, put together by the first
-        caller and shared by all."""
+        """The whole array, read-only: the one block, or the blocks put
+        together by the first caller and shared by all."""
         with self.lock:
             if self._whole is None:
                 whole = self._fill(np.empty(self.shape, dtype=self.dtype))
@@ -511,8 +529,8 @@ class _Shards:
 
     def private_copy(self) -> Tuple[np.ndarray, bool]:
         """A writable copy of the whole array, and whether assembling it
-        was that copy: assembled straight into the copy unless a reader
-        has already assembled the snapshot."""
+        was that copy: assembled straight into the copy unless the
+        snapshot is one block or a reader has already assembled it."""
         with self.lock:
             whole = self._whole
         if whole is not None:
@@ -562,13 +580,13 @@ class Dataset:
                 # alias the caller can mutate behind its back -- one copy at
                 # creation buys a sound invariant: every Dataset buffer is
                 # reachable only through Datasets, or is immutable (a
-                # device array's read-only fetched value, see _snapshot).
+                # device array's read-only fetched blocks, see _snapshot).
                 with _timed(trace, "datamodel.snapshot", bytes=int(arr.nbytes),
                             device=is_device_array(arr)):
                     self._data = self._snapshot(arr, trace)
             else:
-                # Internal zero-copy path (spill load, legacy filter): the
-                # caller guarantees nothing else writes this buffer.  A
+                # Internal zero-copy path (spill load): the caller
+                # guarantees nothing else writes this buffer.  A
                 # read-only buffer (e.g. an np.memmap opened mode="r") stays
                 # shared until the first write triggers the CoW copy.
                 assert arr.dtype == self.dtype, (arr.dtype, self.dtype)
@@ -578,36 +596,15 @@ class Dataset:
 
     def _snapshot(self, arr: Any,
                   trace: Optional[Tuple[Any, str, int, int]] = None
-                  ) -> Union[np.ndarray, _Shards]:
-        """The Dataset's own host buffer holding ``arr``.
-
-        A device array is fetched to the host (``np.asarray``, timed alone as
-        ``datamodel.d2h``).  Its fetched value is adopted as is when it is
-        read-only, of this dtype and contiguous: the ``jax.Array`` is
-        immutable and JAX donates no buffer with an external reference, so
-        nothing can write it, and the CoW layer copies it before the first
-        write through the Dataset.  The order is the device layout's: a TPU
-        keeps narrow-minor arrays such as (N, 2) column-major, so their
-        fetched value is Fortran-ordered, and a C-ordered copy of it would
-        be a transpose.  Otherwise, and for every host array, the value is
-        copied into a fresh C-ordered buffer.
-
-        A device array sharded over several devices is kept as its fetched
-        shards (``_Shards``), each adopted by the same rule; no buffer of
-        the whole array is allocated here."""
-        d2h = 0
+                  ) -> Union[np.ndarray, _DeviceSnapshot]:
+        """The Dataset's own host buffer holding ``arr``: a device array's
+        fetched blocks (``_DeviceSnapshot``; no buffer of the whole array is
+        allocated here), or a host array copied into a fresh C-ordered
+        buffer."""
         if is_device_array(arr):
-            if (not arr.sharding.is_fully_replicated
-                    and arr.is_fully_addressable):
-                return _Shards(arr, self.dtype, trace)
-            d2h = int(arr.nbytes)
-            with _timed(trace, "datamodel.d2h", bytes=d2h):
-                arr = np.asarray(arr)
-            if _adoptable(arr, self.dtype):
-                _TRANSPORT_STATS.record_d2h(d2h)
-                return arr
+            return _DeviceSnapshot(arr, self.dtype, trace)
         out = np.array(arr, dtype=self.dtype, order="C")
-        _TRANSPORT_STATS.record_copy(out.nbytes, d2h=d2h)
+        _TRANSPORT_STATS.record_copy(out.nbytes)
         return out
 
     # -- copy-on-write ------------------------------------------------------
@@ -653,8 +650,8 @@ class Dataset:
         either side copies only that side's bytes (the slab copies its slab,
         not the whole buffer).  This is what a redistributing channel ships --
         the consumer's owned box, zero bytes moved at serve time.  Over a
-        sharded snapshot the slab is a view of the one shard that holds the
-        box, or of the assembled array when no single shard does.
+        device snapshot the slab is a view of the one block that holds the
+        box, or of the assembled array when no single block does.
         """
         slc = tuple(slice(s, s + n) for s, n in zip(starts, shape))
         ds = Dataset.__new__(Dataset)
@@ -665,7 +662,7 @@ class Dataset:
         ds.parent = parent
         ds.ownership = None
         ds._share, base = self._acquire_share()
-        if isinstance(base, _Shards):
+        if isinstance(base, _DeviceSnapshot):
             ds._data, from_shard = base.slab(starts, shape)
         else:
             ds._data, from_shard = base[slc], False
@@ -687,15 +684,16 @@ class Dataset:
     def _ensure_writable(self) -> None:
         """Materialize a private copy if the buffer is shared or read-only
         (memmap, device array -- device buffers are immutable, so a write
-        always lands in a private host copy).  A sharded snapshot is
+        always lands in a private host copy).  A sharded device snapshot is
         assembled straight into the private copy."""
         while True:
             share = self._share
             sched_point("Dataset._ensure_writable", key=("share", id(share)))
             data = self._data
-            # the shards are immutable: their copy needs no share lock, and
+            # the blocks are immutable: their copy needs no share lock, and
             # taking the holder's lock under it would invert the lock order
-            own, assembled = (data.private_copy() if isinstance(data, _Shards)
+            own, assembled = (data.private_copy()
+                              if isinstance(data, _DeviceSnapshot)
                               else (None, False))
             with share.lock:
                 if share is not self._share:
@@ -736,7 +734,7 @@ class Dataset:
     def read_direct(self) -> np.ndarray:
         """The backing array; a read-only alias while the buffer is shared.
 
-        A sharded snapshot is read through its assembled array.
+        A device snapshot is read through its whole array.
         Device-resident buffers (jax arrays) are immutable by construction
         and are returned as-is -- callers see a ``jax.Array`` and may hand it
         straight to the pack-kernel executors without a host round-trip.
@@ -744,7 +742,7 @@ class Dataset:
         data = self._data
         if is_device_array(data):
             return data
-        if isinstance(data, _Shards):
+        if isinstance(data, _DeviceSnapshot):
             data = data.whole()
         _race_point("Dataset.read_direct", data, "r")
         if self._is_exclusive():
@@ -982,12 +980,15 @@ class File(Group):
         return target
 
     @classmethod
-    def load(cls, path: str, mmap: bool = True) -> "File":
+    def load(cls, path: str) -> "File":
+        """The spilled file at ``path``, each dataset a read-only
+        ``np.memmap`` of its segment."""
         with open(path, "rb") as f:
             magic = f.read(len(_SPILL_MAGIC))
             if magic != _SPILL_MAGIC:
-                f.seek(0)
-                return cls._load_legacy(f)
+                raise ValueError(
+                    f"{path!r} is not a spill container (no {_SPILL_MAGIC!r} "
+                    f"magic)")
             hlen = int.from_bytes(f.read(8), "little")
             meta = json.loads(f.read(hlen).decode())
             data_start = _align_up(len(_SPILL_MAGIC) + 8 + hlen)
@@ -996,19 +997,13 @@ class File(Group):
             for dpath, info in meta["datasets"].items():
                 dt = np.dtype(info["dtype"])
                 shape = tuple(info["shape"])
-                nbytes = int(info["nbytes"])
                 off = data_start + int(info["offset"])
-                if nbytes == 0:
+                if int(info["nbytes"]) == 0:
                     arr = np.zeros(shape, dtype=dt)
-                elif mmap:
+                else:
                     mm = np.memmap(path, dtype=dt, mode="r", offset=off,
                                    shape=shape if shape else (1,))
                     arr = mm if shape else mm.reshape(())
-                else:
-                    f.seek(off)
-                    buf = f.read(nbytes)
-                    _TRANSPORT_STATS.record_copy(nbytes)
-                    arr = np.frombuffer(bytearray(buf), dtype=dt).reshape(shape)
                 ds = out.create_dataset(dpath, data=arr, copy=False)
                 ds.attrs.update(info.get("attrs") or {})
                 own = info.get("ownership")
@@ -1018,24 +1013,6 @@ class File(Group):
                         bo.add(int(r), s, sh)
                     ds.ownership = bo
             return out
-
-    @classmethod
-    def _load_legacy(cls, f) -> "File":
-        # pre-raw-container format: u64 header length + json + npz blob
-        hlen = int.from_bytes(f.read(8), "little")
-        meta = json.loads(f.read(hlen).decode())
-        npz = np.load(io.BytesIO(f.read()))
-        out = cls(meta["filename"])
-        for dpath, info in meta["datasets"].items():
-            ds = out.create_dataset(dpath, data=npz[info["key"]], copy=False)
-            ds.attrs.update(info.get("attrs") or {})
-            own = info.get("ownership")
-            if own:
-                bo = BlockOwnership()
-                for r, (s, sh) in own.items():
-                    bo.add(int(r), s, sh)
-                ds.ownership = bo
-        return out
 
     def total_bytes(self) -> int:
         return sum(d.nbytes for d in self.visit_datasets())

@@ -229,10 +229,6 @@ class Wilkins:
     record_events: keep per-channel event timelines (Gantt / Fig. 5).
     max_restarts:  per-instance restart budget on task failure (fault tolerance).
     action_dirs:   extra directories to search for custom action scripts.
-    zero_copy:     transport fast path (default True): channels ship CoW
-                   dataset views and fan-out shares one filtered payload.
-                   False restores the legacy materialize-per-channel copies
-                   (the benchmark baseline).  See DESIGN.md.
 
     ``run()`` owns the prefetch-executor lifecycle: a fresh ``PrefetchPool``
     sized to the workflow's total per-edge prefetch depth is injected into
@@ -250,7 +246,6 @@ class Wilkins:
         record_events: bool = False,
         max_restarts: int = 0,
         action_dirs: Sequence[str] = (),
-        zero_copy: bool = True,
     ):
         self.graph = config if isinstance(config, WorkflowGraph) else WorkflowGraph.from_yaml(config)
         self.funcs = dict(funcs)
@@ -261,7 +256,6 @@ class Wilkins:
         self.record_events = record_events
         self.max_restarts = max_restarts
         self.action_dirs = list(action_dirs)
-        self.zero_copy = zero_copy
 
         # Per-task failure policies: YAML ``on_failure:`` wins; a task that
         # declared nothing inherits the legacy ``max_restarts`` budget as an
@@ -374,7 +368,6 @@ class Wilkins:
                     spill_dir=self.spill_dir,
                     record_events=self.record_events,
                     queue_depth=edge.queue_depth,
-                    zero_copy=self.zero_copy,
                     redistribute=redist,
                     prefetch=edge.prefetch,
                     weight=edge.weight,

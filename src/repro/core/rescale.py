@@ -395,7 +395,6 @@ def _execute(driver: Any, sup: Any, op: RescaleOp) -> None:
                     spill_dir=driver.spill_dir,
                     record_events=driver.record_events,
                     queue_depth=edge.queue_depth,
-                    zero_copy=driver.zero_copy,
                     redistribute=redist,
                     prefetch=edge.prefetch,
                     weight=edge.weight,

@@ -238,8 +238,9 @@ def test_snapshot_falls_back_to_a_copy(case):
 
 
 # ---------------------------------------------------------------------------
-# the snapshot of a device array sharded over several devices: assembled
-# from its distinct shards into the Dataset's own buffer, in the open
+# the snapshot of a device array on four devices: kept as its fetched
+# blocks, one per distinct shard, and assembled only where a read needs the
+# whole of a sharded array; a one-device array is the one-block case
 # ---------------------------------------------------------------------------
 SHARDED_SNAPSHOTS = textwrap.dedent("""
     import json, tempfile, traceback
@@ -402,11 +403,47 @@ SHARDED_SNAPSHOTS = textwrap.dedent("""
         assert d == ASSEMBLE, d
         np.testing.assert_array_equal(File.load(path)["/d"][:], g)
 
+    def one_device():
+        x = jax.device_put(g, devs[1])
+        ds, d = snap(x)
+        assert d == dict({k: 0 for k in KEYS}, bytes_d2h=g.nbytes,
+                         snapshots_adopted=1), d
+        block = np.asarray(x.addressable_shards[0].data)  # the one block
+        got, d = counted(lambda: ds[:])
+        assert d == {k: 0 for k in KEYS}, d
+        assert np.shares_memory(got, block)
+        np.testing.assert_array_equal(got, g)
+        slab, d = counted(lambda: ds.slab_view((40, 2, 0), (8, 12, 16)))
+        assert d == {k: 0 for k in KEYS}, d
+        assert np.shares_memory(slab[:], block)
+        np.testing.assert_array_equal(slab[:], g[40:48, 2:14])
+        tr = SpanRecorder()
+        ds, _ = snap(x, trace=(tr, "nyx", 0, 3))
+        np.testing.assert_array_equal(ds[:], g)
+        spans = [s for s in tr.spans() if s["ph"] == "X"]
+        d2h = [s for s in spans if s["name"] == "datamodel.d2h"]
+        assert len(d2h) == 1, d2h
+        assert d2h[0]["args"]["device_id"] == devs[1].id, d2h
+        assert d2h[0]["args"]["bytes"] == g.nbytes, d2h
+        assert not [s for s in spans
+                    if s["name"] == "datamodel.assemble"], spans
+
+    def one_device_write():
+        x = jax.device_put(g, devs[1])
+        ds, _ = snap(x)
+        _, d = counted(lambda: ds.__setitem__(0, -1.0))
+        assert d == dict({k: 0 for k in KEYS}, bytes_copied=g.nbytes,
+                         cow_copies=1), d
+        want = g.copy()
+        want[0] = -1.0
+        np.testing.assert_array_equal(ds[:], want)
+        np.testing.assert_array_equal(np.asarray(x), g)
+
     out = {}
     for case in (sharded_axis0, sharded_traced, replicated, mesh_2x2,
                  write_after, slab_in_shard, slab_across_shards,
                  views_assemble_once, mesh_2x2_slab, write_after_slab,
-                 spill_round_trip):
+                 spill_round_trip, one_device, one_device_write):
         try:
             case()
             out[case.__name__] = "ok"
@@ -434,14 +471,15 @@ def sharded_snapshots():
     "sharded_axis0", "sharded_traced", "replicated", "mesh_2x2",
     "write_after", "slab_in_shard", "slab_across_shards",
     "views_assemble_once", "mesh_2x2_slab", "write_after_slab",
-    "spill_round_trip"])
+    "spill_round_trip", "one_device", "one_device_write"])
 def test_sharded_device_snapshot(sharded_snapshots, case):
     """On four CPU devices: a field sharded on axis 0 is kept as its fetched
     shards, with no host copy at write (a d2h span per shard under the
     snapshot span); a slab inside one shard is a view of that shard; a read
     of the whole, a slab across shards, a write or a spill assembles the
     array once per snapshot (an assemble span per shard, on the write's
-    step), shared by every view; a fully replicated array is adopted as
-    before; a (2, 2) mesh fetches each distinct block once; a write through
-    the Dataset leaves the device array and sibling slabs as they were."""
+    step), shared by every view; a fully replicated array and an array on
+    one device are one block, adopted and never assembled; a (2, 2) mesh
+    fetches each distinct block once; a write through the Dataset leaves
+    the device array and sibling slabs as they were."""
     assert sharded_snapshots[case] == "ok", sharded_snapshots[case]

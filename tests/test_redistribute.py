@@ -477,24 +477,6 @@ def test_redist_slab_is_cow_protected():
     assert not np.shares_memory(slab.read_direct(), src.read_direct())
 
 
-def test_legacy_mode_honors_redistribute_contract():
-    """zero_copy=False still ships only the owned slab (eagerly copied)."""
-    f = File("o.h5")
-    src = f.create_dataset("/g", data=np.arange(16.0))
-    ch = Channel("c", ("p", 0), ("c", 0), "o.h5", ["/g"], zero_copy=False,
-                 redistribute=RedistSpec(axis=0, nslots=2, slot=1, nranks=1))
-    reset_transport_stats()
-    out = ch.filter_file(f)
-    slab = out["/g"]
-    assert slab.shape == (8,)
-    assert tuple(slab.attrs["redist_box_starts"]) == (8,)
-    assert slab.ownership.blocks == {0: ((8,), (8,))}
-    assert not np.shares_memory(slab.read_direct(), src.read_direct())
-    np.testing.assert_array_equal(slab[:], np.arange(8.0, 16.0))
-    # legacy copies eagerly -- but only the slab's bytes, not the whole file
-    assert transport_stats().snapshot()["bytes_copied"] == 8 * 8
-
-
 def test_pack_all_pads_once_and_matches_per_rank():
     import jax.numpy as jnp
 

@@ -140,24 +140,6 @@ tasks:
         assert np.shares_memory(received[0], a)
 
 
-def test_legacy_mode_materializes_copies():
-    vol = VOL("producer")
-    chans = [
-        Channel(f"p->c{i}", ("producer", 0), ("consumer", i), "o.h5", ["/g"],
-                zero_copy=False)
-        for i in range(3)
-    ]
-    vol.outgoing.extend(chans)
-    f = File("o.h5")
-    src = f.create_dataset("/g", data=np.zeros(512))
-    reset_transport_stats()
-    vol.on_file_close(f)
-    assert transport_stats().snapshot()["bytes_copied"] == 3 * src.nbytes
-    for c in chans:
-        g = c.get(timeout=5)
-        assert not np.shares_memory(g["/g"].read_direct(), src.read_direct())
-
-
 # ---------------------------------------------------------------------------
 # raw mmap spill container
 # ---------------------------------------------------------------------------
@@ -188,7 +170,7 @@ def test_spill_load_is_mmap_backed_and_aligned(tmp_path):
     path = f.save(str(tmp_path))
 
     reset_transport_stats()
-    g = File.load(path, mmap=True)
+    g = File.load(path)
     assert transport_stats().snapshot()["bytes_copied"] == 0  # zero-copy load
     assert isinstance(g["/a"].read_direct(), np.memmap) or isinstance(
         g["/a"].read_direct().base, np.memmap)
@@ -202,6 +184,13 @@ def test_spill_load_is_mmap_backed_and_aligned(tmp_path):
         meta = json.loads(fh.read(hlen).decode())
     for info in meta["datasets"].values():
         assert info["offset"] % 64 == 0
+
+
+def test_spill_load_rejects_unknown_container(tmp_path):
+    path = tmp_path / "not_a_spill.h5"
+    path.write_bytes(b"PK\x03\x04" + bytes(60))
+    with pytest.raises(ValueError, match="not a spill container"):
+        File.load(str(path))
 
 
 def test_spill_roundtrip_empty_and_scalar_datasets(tmp_path):
