@@ -27,7 +27,7 @@ established; the checker turns the convention into an enforced rule::
     25  scheduler      SchedulerRuntime._lock/_tick_lock, PrefetchPool cv
     30  channel.cv     the per-channel condition variable
     40  channel.sem    ResizableSemaphore cv, supervisor heartbeat lock
-    45  datamodel      a sharded snapshot's assembly (records spans, stats)
+    45  datamodel      a device snapshot's fetch and assembly (spans, stats)
     50  leaf           mux, telemetry, stats, fault plans, driver misc
 
 Same-rank nesting is allowed only for ranks declaring it (the serve locks

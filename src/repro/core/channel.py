@@ -950,6 +950,24 @@ class Channel:
         stats.record_redistribution(planned, v.nbytes, ds.nbytes, aligned=False)
 
     # ------------------------------------------------------------- producer
+    def _skips_some(self, close_count: int) -> bool:
+        """Does a `some` edge drop the close that brings its count to
+        ``close_count``?  The one rule ``offer`` applies and
+        ``drops_next_close`` forecasts."""
+        return (self.strategy == FlowControl.SOME
+                and close_count % self.freq != 0)
+
+    def drops_next_close(self) -> bool:
+        """Will ``offer`` drop the next close, from the state as it stands?
+
+        True for an abandoned channel, and on a `some` edge for every close
+        but each ``freq``-th.  Never for `all`, and never for `latest`,
+        whose decision waits for a consumer at close time.  A forecast
+        only: a quarantine or rescale that rewinds the close count, or a
+        second matching file closed first, can make it wrong."""
+        with self._lock:
+            return self._abandoned or self._skips_some(self._close_count + 1)
+
     def offer(self, f: File, _payload_cache: Optional[Dict[Any, File]] = None) -> bool:
         """Producer-side serve with flow control. Returns True if served.
 
@@ -980,7 +998,7 @@ class Channel:
                 return False
             self._close_count += 1
             step = self._close_count - 1
-            if self.strategy == FlowControl.SOME and (self._close_count % self.freq) != 0:
+            if self._skips_some(self._close_count):
                 self.stats.dropped += 1
                 self._event_locked("producer", "skip_some")
                 return False

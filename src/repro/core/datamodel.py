@@ -36,7 +36,10 @@ Ownership rules (see DESIGN.md):
   A one-device or fully replicated array is the one-block case, whose block
   is the whole array.  A slab inside one block is a view of that block, and
   anything else that needs the whole of a sharded array assembles it once
-  per snapshot, shared by every view.
+  per snapshot, shared by every view.  Where every channel will drop the
+  file's close (``defer``), the array is kept unfetched and fetched by the
+  first read that needs its bytes; the VOL fetches a served file's before
+  its close returns.
 * ``Dataset`` mutation goes through ``__setitem__`` / ``write_slab``; both
   materialize a private copy first if the buffer is shared or read-only
   (memmap, adopted device value).  Copies are counted in
@@ -132,7 +135,10 @@ class TransportStats:
     (``slabs_from_shard``), and a read that needs the whole sharded array
     assembles it once per snapshot (``snapshots_assembled``, in
     ``bytes_copied``); ``views`` counts zero-copy dataset views handed
-    out.
+    out.  A device snapshot kept unfetched at write, because every channel
+    will drop its file's close, counts in ``snapshots_deferred``, and one
+    that a read fetched later (a forecast missed) in ``deferred_fetched``;
+    its bytes count in ``bytes_d2h`` when they are fetched.
     Benchmarks reset + read these to measure the Fig. 4 overhead lever.
     """
 
@@ -145,6 +151,8 @@ class TransportStats:
         self.snapshots_sharded = 0
         self.snapshots_assembled = 0
         self.slabs_from_shard = 0
+        self.snapshots_deferred = 0
+        self.deferred_fetched = 0
         self.cow_copies = 0
         self.views = 0
         # M->N redistribution accounting (planned vs shipped vs whole-file):
@@ -203,6 +211,15 @@ class TransportStats:
             elif adopted:
                 self.snapshots_adopted += 1
 
+    def record_deferred(self, fetched: bool = False) -> None:
+        """A device snapshot kept unfetched at write, or (``fetched``) one
+        that a read fetched later."""
+        with self._lock:
+            if fetched:
+                self.deferred_fetched += 1
+            else:
+                self.snapshots_deferred += 1
+
     def record_view(self, from_shard: bool = False) -> None:
         """A zero-copy view handed out; ``from_shard``: a slab served from
         one shard of a sharded snapshot."""
@@ -255,6 +272,8 @@ class TransportStats:
                 "snapshots_sharded": self.snapshots_sharded,
                 "snapshots_assembled": self.snapshots_assembled,
                 "slabs_from_shard": self.slabs_from_shard,
+                "snapshots_deferred": self.snapshots_deferred,
+                "deferred_fetched": self.deferred_fetched,
                 "cow_copies": self.cow_copies,
                 "views": self.views,
                 "redist_planned_bytes": self.redist_planned_bytes,
@@ -277,6 +296,7 @@ class TransportStats:
             self.bytes_d2h = self.snapshots_adopted = 0
             self.snapshots_sharded = self.snapshots_assembled = 0
             self.slabs_from_shard = 0
+            self.snapshots_deferred = self.deferred_fetched = 0
             self.redist_planned_bytes = self.redist_shipped_bytes = 0
             self.redist_baseline_bytes = 0
             self.redist_aligned = self.redist_slabs = 0
@@ -454,15 +474,22 @@ class _DeviceSnapshot:
     private copy.  ``slab`` serves a box that lies inside one block as a
     view of that block; ``whole`` is the one block, or assembles the blocks
     of a sharded array for any other read, once per snapshot under
-    ``lock``, and every later reader reuses the result."""
+    ``lock``, and every later reader reuses the result.
 
-    __slots__ = ("shape", "dtype", "blocks", "trace", "lock", "_whole")
+    A ``deferred`` snapshot (every channel will drop its file's close) holds
+    the ``jax.Array`` unfetched instead: the first read that needs bytes
+    (``slab``, ``whole``, ``private_copy``, and so a spill) fetches it under
+    ``lock``, once, and one that nothing reads is never fetched.  ``release``
+    lets the array go unfetched when the file is dropped: the file's tree
+    is a reference cycle, so without it the device memory would wait for
+    the garbage collector."""
+
+    __slots__ = ("shape", "dtype", "blocks", "trace", "lock", "_whole",
+                 "_arr")
 
     def __init__(self, arr: Any, dtype: np.dtype,
-                 trace: Optional[Tuple[Any, str, int, int]]):
-        """Fetch ``arr``'s distinct shards: start every transfer, then wait
-        for each under its own ``datamodel.d2h`` span and adopt its value,
-        or copy it into a C-ordered block when it cannot be adopted."""
+                 trace: Optional[Tuple[Any, str, int, int]],
+                 deferred: bool = False):
         if not arr.is_fully_addressable:
             raise ValueError(
                 "cannot snapshot a jax.Array with shards on devices of "
@@ -470,35 +497,85 @@ class _DeviceSnapshot:
         self.shape = tuple(arr.shape)
         self.dtype = dtype
         self.trace = trace
-        self.lock = make_lock("datamodel:assemble")
+        self.lock = make_lock("datamodel:snapshot")
+        # empty until fetched, and then never again
+        self.blocks: List[Tuple[Tuple[int, ...], Tuple[int, ...],
+                                np.ndarray, int]] = []
+        self._whole: Optional[np.ndarray] = None
+        # the unfetched array of a deferred snapshot, None once fetched or
+        # released
+        self._arr: Any = None
+        if deferred:
+            self._arr = arr
+            _TRANSPORT_STATS.record_deferred()
+        else:
+            self._fetch(arr)
+
+    def _fetch(self, arr: Any) -> None:
+        """Fetch ``arr``'s distinct shards: start every transfer, then wait
+        for each under its own ``datamodel.d2h`` span and adopt its value,
+        or copy it into a C-ordered block when it cannot be adopted."""
         shards = [s for s in arr.addressable_shards if s.replica_id == 0]
         for s in shards:
             s.data.copy_to_host_async()
-        self.blocks = []
+        blocks = []
         adopted = True
         for s in shards:
             dev = s.device.id
-            with _timed(trace, "datamodel.d2h", bytes=int(s.data.nbytes),
-                        device_id=dev):
+            with _timed(self.trace, "datamodel.d2h",
+                        bytes=int(s.data.nbytes), device_id=dev):
                 value = np.asarray(s.data)
-            if not _adoptable(value, dtype):
-                value = np.array(value, dtype=dtype, order="C")
+            if not _adoptable(value, self.dtype):
+                value = np.array(value, dtype=self.dtype, order="C")
                 value.flags.writeable = False
                 _TRANSPORT_STATS.record_copy(value.nbytes)
                 adopted = False
             box = [sl.indices(n)[:2] for sl, n in zip(s.index, self.shape)]
-            self.blocks.append((tuple(a for a, _ in box),
-                                tuple(z for _, z in box), value, dev))
+            blocks.append((tuple(a for a, _ in box),
+                           tuple(z for _, z in box), value, dev))
+        self.blocks = blocks
         # one block covers the whole array: it is never assembled
-        self._whole: Optional[np.ndarray] = (
-            self.blocks[0][2] if len(self.blocks) == 1 else None)
-        _TRANSPORT_STATS.record_d2h(arr.nbytes, len(self.blocks), adopted)
+        if len(blocks) == 1:
+            self._whole = blocks[0][2]
+        _TRANSPORT_STATS.record_d2h(arr.nbytes, len(blocks), adopted)
+
+    def _fetch_locked(self) -> None:
+        """Fetch a deferred snapshot (the caller holds ``lock``)."""
+        if self.blocks:
+            return
+        arr = self._arr
+        if arr is None:
+            raise ValueError(
+                "the device array of this snapshot was let go unfetched "
+                "when flow control dropped its file's close; read a "
+                "dataset of such a file before the file closes")
+        if arr.is_deleted():
+            raise ValueError(
+                "the device array of this snapshot was deleted or donated "
+                "before anything read it: its file was written with the "
+                "array unfetched, so the array must outlive the file's "
+                "close")
+        self._fetch(arr)
+        self._arr = None
+        _TRANSPORT_STATS.record_deferred(fetched=True)
+
+    def fetch(self) -> None:
+        """Fetch the array now if the snapshot was deferred."""
+        if not self.blocks:
+            with self.lock:
+                self._fetch_locked()
+
+    def release(self) -> None:
+        """Let an unfetched array go: nothing will read this snapshot."""
+        with self.lock:
+            self._arr = None
 
     def slab(self, starts: Sequence[int],
              shape: Sequence[int]) -> Tuple[np.ndarray, bool]:
         """The box as a view of the one block that holds all of it, else
         of the assembled array; and whether it came from one shard of a
         sharded array."""
+        self.fetch()
         stops = [s + n for s, n in zip(starts, shape)]
         for lo, hi, value, _ in self.blocks:
             if all(l <= s and z <= h
@@ -520,6 +597,7 @@ class _DeviceSnapshot:
         """The whole array, read-only: the one block, or the blocks put
         together by the first caller and shared by all."""
         with self.lock:
+            self._fetch_locked()
             if self._whole is None:
                 whole = self._fill(np.empty(self.shape, dtype=self.dtype))
                 whole.flags.writeable = False
@@ -532,6 +610,7 @@ class _DeviceSnapshot:
         was that copy: assembled straight into the copy unless the
         snapshot is one block or a reader has already assembled it."""
         with self.lock:
+            self._fetch_locked()
             whole = self._whole
         if whole is not None:
             return np.array(whole), False
@@ -556,9 +635,13 @@ class Dataset:
         parent: Optional["Group"] = None,
         copy: bool = True,
         trace: Optional[Tuple[Any, str, int, int]] = None,
+        defer: bool = False,
     ):
         """``trace``: ``(recorder, task, instance, step)`` of a traced
-        workflow's write, which times the snapshot of ``data`` as spans."""
+        workflow's write, which times the snapshot of ``data`` as spans.
+        ``defer``: keep a device array unfetched until a read needs it (its
+        file's close will be dropped); a host array is copied all the
+        same."""
         self.name = name
         self.shape = tuple(int(s) for s in shape)
         self.dtype = np.dtype(dtype)
@@ -582,8 +665,8 @@ class Dataset:
                 # reachable only through Datasets, or is immutable (a
                 # device array's read-only fetched blocks, see _snapshot).
                 with _timed(trace, "datamodel.snapshot", bytes=int(arr.nbytes),
-                            device=is_device_array(arr)):
-                    self._data = self._snapshot(arr, trace)
+                            device=is_device_array(arr), deferred=defer):
+                    self._data = self._snapshot(arr, trace, defer)
             else:
                 # Internal zero-copy path (spill load): the caller
                 # guarantees nothing else writes this buffer.  A
@@ -595,14 +678,15 @@ class Dataset:
             self._data = np.zeros(self.shape, dtype=self.dtype)
 
     def _snapshot(self, arr: Any,
-                  trace: Optional[Tuple[Any, str, int, int]] = None
+                  trace: Optional[Tuple[Any, str, int, int]] = None,
+                  defer: bool = False
                   ) -> Union[np.ndarray, _DeviceSnapshot]:
         """The Dataset's own host buffer holding ``arr``: a device array's
         fetched blocks (``_DeviceSnapshot``; no buffer of the whole array is
-        allocated here), or a host array copied into a fresh C-ordered
-        buffer."""
+        allocated here), unfetched if ``defer``, or a host array copied
+        into a fresh C-ordered buffer."""
         if is_device_array(arr):
-            return _DeviceSnapshot(arr, self.dtype, trace)
+            return _DeviceSnapshot(arr, self.dtype, trace, deferred=defer)
         out = np.array(arr, dtype=self.dtype, order="C")
         _TRANSPORT_STATS.record_copy(out.nbytes)
         return out
@@ -806,8 +890,10 @@ class Group:
         data: Optional[np.ndarray] = None,
         copy: bool = True,
         trace: Optional[Tuple[Any, str, int, int]] = None,
+        defer: bool = False,
     ) -> Dataset:
-        """A new dataset at ``path``; ``trace`` as for ``Dataset``."""
+        """A new dataset at ``path``; ``trace`` and ``defer`` as for
+        ``Dataset``."""
         comps = split_path(path)
         if not comps:
             raise ValueError("empty dataset path")
@@ -820,7 +906,7 @@ class Group:
         if shape is None or dtype is None:
             raise ValueError("need shape+dtype or data")
         ds = Dataset(comps[-1], tuple(shape), dtype, data=data, parent=parent,
-                     copy=copy, trace=trace)
+                     copy=copy, trace=trace, defer=defer)
         parent.children[comps[-1]] = ds
         return ds
 
@@ -1016,6 +1102,21 @@ class File(Group):
 
     def total_bytes(self) -> int:
         return sum(d.nbytes for d in self.visit_datasets())
+
+    def fetch_deferred(self) -> None:
+        """Fetch every device snapshot of this file kept unfetched at
+        write: a served file's, before its array can be donated."""
+        for ds in self.visit_datasets():
+            if isinstance(ds._data, _DeviceSnapshot):
+                ds._data.fetch()
+
+    def release_deferred(self) -> None:
+        """Let go of every device array this file's snapshots still hold
+        unfetched: a dropped file's, whose device memory would otherwise
+        wait for the garbage collector (the tree is a reference cycle)."""
+        for ds in self.visit_datasets():
+            if isinstance(ds._data, _DeviceSnapshot):
+                ds._data.release()
 
     def copy_meta_only(self) -> "File":
         """Shallow structural copy (metadata broadcast path, cf. Listing 5)."""

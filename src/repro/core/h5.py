@@ -62,8 +62,12 @@ class _H5File:
         # step: the close the VOL counts next
         trace = None if tr is None else (
             tr, vol.task, vol.instance, vol.file_close_counter)
+        # a file whose close every channel will drop keeps a device array
+        # unfetched: nothing reads it unless the forecast misses
+        defer = (vol is not None and datamodel.is_device_array(data)
+                 and vol.drops_close(self._inner.filename))
         ds = self._inner.create_dataset(path, shape=shape, dtype=dtype,
-                                        data=data, trace=trace)
+                                        data=data, trace=trace, defer=defer)
         if ownership is not None:
             ds.ownership = ownership
         if vol is not None:

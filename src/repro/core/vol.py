@@ -192,10 +192,12 @@ class VOL:
         they intentionally miss each other's cache entries.
         """
         n = 0
+        served: List[File] = []
         sched_point("VOL.serve_all", key=("vol", id(self)))
         with self.serve_lock:
             for f in list(self._unserved):
                 payload_cache: Dict[Any, File] = {}
+                n0 = n
                 for ch in self.outgoing:
                     if not ch.matches_file(f.filename):
                         continue
@@ -205,9 +207,30 @@ class VOL:
                         continue
                     if ch.offer(f, _payload_cache=payload_cache):
                         n += 1
+                if n > n0:
+                    served.append(f)
+        # a snapshot kept unfetched on a forecast drop that was served after
+        # all is fetched before the close returns: task code may donate or
+        # delete the array once it does
+        for f in served:
+            f.fetch_deferred()
         return n
 
+    def drops_close(self, filename: str) -> bool:
+        """Will every channel drop this file's next close?  Only where one
+        matches at all, and never under an ``after_file_close`` action,
+        which decides itself when to serve."""
+        if self._cb["after_file_close"] is not None:
+            return False
+        with self.serve_lock:
+            chans = [ch for ch in self.outgoing if ch.matches_file(filename)]
+            return bool(chans) and all(ch.drops_next_close() for ch in chans)
+
     def clear_files(self) -> None:
+        """Drop the unserved files.  A served file's deferred snapshots
+        were fetched at its serve; a dropped one's are let go here."""
+        for f in self._unserved:
+            f.release_deferred()
         self._unserved.clear()
 
     def broadcast_files(self) -> None:

@@ -238,6 +238,131 @@ def test_snapshot_falls_back_to_a_copy(case):
 
 
 # ---------------------------------------------------------------------------
+# a deferred snapshot (its file's close will be dropped) keeps the device
+# array unfetched; the first read that needs bytes fetches it, once
+# ---------------------------------------------------------------------------
+DEFERRED = ("bytes_d2h", "snapshots_deferred", "deferred_fetched")
+
+
+def _deferred_delta(s0):
+    from repro.core.datamodel import transport_stats
+    s1 = transport_stats().snapshot()
+    return {k: s1[k] - s0[k] for k in DEFERRED}
+
+
+def _read_as(kind, ds, spill_dir):
+    """What a reader of ``kind`` gets from ``ds``: the whole, a slab, a
+    write's private copy, or the spilled and reloaded file."""
+    if kind == "whole":
+        return np.array(ds[:])
+    if kind == "slab":
+        return np.array(ds.slab_view((2, 0), (3, 4))[:])
+    if kind == "private_copy":
+        ds[0] = -1.0
+        return np.array(ds[:])
+    return np.array(File.load(ds.parent.save(spill_dir))["/d"][:])
+
+
+def _read_expected(kind, want):
+    if kind == "slab":
+        return want[2:5, :4]
+    if kind == "private_copy":
+        out = want.copy()
+        out[0] = -1.0
+        return out
+    return want
+
+
+def test_deferred_snapshot_fetches_nothing_at_write():
+    import jax.numpy as jnp
+
+    from repro.core.datamodel import transport_stats
+
+    x = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
+    s0 = transport_stats().snapshot()
+    ds = File("a.h5").create_dataset("/d", data=x, defer=True)
+    assert _deferred_delta(s0) == {"bytes_d2h": 0, "snapshots_deferred": 1,
+                                   "deferred_fetched": 0}
+    assert ds.shape == (8, 4) and ds.dtype == np.float32
+    s0 = transport_stats().snapshot()
+    host = File("a.h5").create_dataset("/d", data=np.asarray(x), defer=True)
+    assert _deferred_delta(s0) == {k: 0 for k in DEFERRED}  # copied as ever
+    np.testing.assert_array_equal(host[:], np.asarray(x))
+
+
+@pytest.mark.parametrize("kind", ["whole", "slab", "private_copy", "save"])
+def test_deferred_snapshot_first_read_fetches_once(kind, tmp_path):
+    """More readers than cores race to a deferred one-device snapshot: one
+    fetch (one ``datamodel.d2h`` span with the write's step), exact values
+    for every reader."""
+    import threading
+
+    import jax.numpy as jnp
+
+    from repro.core.datamodel import transport_stats
+    from repro.obs import SpanRecorder
+
+    n = 2 * (os.cpu_count() or 4)
+    # 4 MiB: a fetch long enough for the readers to overlap in it
+    want = np.arange(1 << 20, dtype=np.float32).reshape(1 << 12, 1 << 8)
+    x = jnp.asarray(want)
+    tr = SpanRecorder()
+    f = File("a.h5")
+    f.create_dataset("/d", data=x, defer=True, trace=(tr, "nyx", 0, 7))
+    readers = [f.view()["/d"] for _ in range(n)]
+    gate = threading.Barrier(n)
+    got = [None] * n
+    s0 = transport_stats().snapshot()
+
+    def read(i):
+        gate.wait()
+        got[i] = _read_as(kind, readers[i], str(tmp_path / str(i)))
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        ts = [threading.Thread(target=read, args=(i,)) for i in range(n)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=30)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert _deferred_delta(s0) == {"bytes_d2h": x.nbytes,
+                                   "snapshots_deferred": 0,
+                                   "deferred_fetched": 1}
+    for g in got:
+        np.testing.assert_array_equal(g, _read_expected(kind, want))
+    d2h = [s for s in tr.spans()
+           if s["ph"] == "X" and s["name"] == "datamodel.d2h"]
+    assert len(d2h) == 1 and d2h[0]["step"] == 7, d2h
+    s0 = transport_stats().snapshot()
+    np.testing.assert_array_equal(f["/d"][:], want)  # the fetch is kept
+    assert _deferred_delta(s0) == {k: 0 for k in DEFERRED}
+
+
+@pytest.mark.parametrize("end", ["delete", "donate"])
+def test_deferred_snapshot_of_a_dead_array_raises(end):
+    """A deferred snapshot never returns other bytes: an array deleted or
+    donated before the fetch is an error that names the cause."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.arange(16, dtype=jnp.float32) + 1.0
+    ds = File("a.h5").create_dataset("/d", data=x, defer=True)
+    if end == "delete":
+        x.delete()
+    else:
+        jax.jit(lambda a: a * 2.0, donate_argnums=0)(x)
+    assert x.is_deleted()
+    with pytest.raises(ValueError, match="deleted or donated"):
+        ds[:]
+    kept = File("a.h5").create_dataset("/d", data=jnp.ones(4), defer=True)
+    np.testing.assert_array_equal(kept[:], np.ones(4, np.float32))
+
+
+# ---------------------------------------------------------------------------
 # the snapshot of a device array on four devices: kept as its fetched
 # blocks, one per distinct shard, and assembled only where a read needs the
 # whole of a sharded array; a one-device array is the one-block case
@@ -439,11 +564,83 @@ SHARDED_SNAPSHOTS = textwrap.dedent("""
         np.testing.assert_array_equal(ds[:], want)
         np.testing.assert_array_equal(np.asarray(x), g)
 
+    DEFERRED = ("bytes_d2h", "snapshots_deferred", "deferred_fetched")
+
+    def deferred_delta(s0):
+        s1 = transport_stats().snapshot()
+        return {k: s1[k] - s0[k] for k in DEFERRED}
+
+    def deferred_reads():
+        # two concurrent readers of each kind: one fetch of both shards
+        import threading
+        for kind in ("whole", "slab", "private_copy", "save"):
+            tr = SpanRecorder()
+            f = File("a.h5")
+            s0 = transport_stats().snapshot()
+            f.create_dataset("/d", data=sharded(), defer=True,
+                             trace=(tr, "nyx", 0, 5))
+            d = deferred_delta(s0)
+            assert d == {"bytes_d2h": 0, "snapshots_deferred": 1,
+                         "deferred_fetched": 0}, (kind, d)
+            readers = [f.view()["/d"] for _ in range(2)]
+            gate = threading.Barrier(2)
+            got = [None, None]
+
+            def read(i):
+                ds = readers[i]
+                gate.wait()
+                if kind == "whole":
+                    got[i] = np.array(ds[:])
+                elif kind == "slab":
+                    got[i] = np.array(ds.slab_view((40, 2, 0),
+                                                   (8, 12, 16))[:])
+                elif kind == "private_copy":
+                    ds[0] = -1.0
+                    got[i] = np.array(ds[:])
+                else:
+                    got[i] = np.array(File.load(ds.parent.save(
+                        tempfile.mkdtemp()))["/d"][:])
+
+            s0 = transport_stats().snapshot()
+            ts = [threading.Thread(target=read, args=(i,)) for i in range(2)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join()
+            d = deferred_delta(s0)
+            assert d == {"bytes_d2h": g.nbytes, "snapshots_deferred": 0,
+                         "deferred_fetched": 1}, (kind, d)
+            want = g
+            if kind == "slab":
+                want = g[40:48, 2:14]
+            elif kind == "private_copy":
+                want = g.copy()
+                want[0] = -1.0
+            for got_i in got:
+                np.testing.assert_array_equal(got_i, want)
+            d2h = [s for s in tr.spans() if s["ph"] == "X"
+                   and s["name"] == "datamodel.d2h"]
+            assert len(d2h) == 2 and {s["step"] for s in d2h} == {5}, d2h
+            assert {s["args"]["device_id"] for s in d2h} == {
+                devs[0].id, devs[1].id}, d2h
+
+    def deferred_deleted():
+        x = sharded()
+        ds = Dataset("d", x.shape, x.dtype, data=x, defer=True)
+        x.delete()
+        try:
+            ds[:]
+        except ValueError as e:
+            assert "deleted or donated" in str(e), e
+        else:
+            raise AssertionError("a deleted array was read")
+
     out = {}
     for case in (sharded_axis0, sharded_traced, replicated, mesh_2x2,
                  write_after, slab_in_shard, slab_across_shards,
                  views_assemble_once, mesh_2x2_slab, write_after_slab,
-                 spill_round_trip, one_device, one_device_write):
+                 spill_round_trip, one_device, one_device_write,
+                 deferred_reads, deferred_deleted):
         try:
             case()
             out[case.__name__] = "ok"
@@ -471,7 +668,8 @@ def sharded_snapshots():
     "sharded_axis0", "sharded_traced", "replicated", "mesh_2x2",
     "write_after", "slab_in_shard", "slab_across_shards",
     "views_assemble_once", "mesh_2x2_slab", "write_after_slab",
-    "spill_round_trip", "one_device", "one_device_write"])
+    "spill_round_trip", "one_device", "one_device_write",
+    "deferred_reads", "deferred_deleted"])
 def test_sharded_device_snapshot(sharded_snapshots, case):
     """On four CPU devices: a field sharded on axis 0 is kept as its fetched
     shards, with no host copy at write (a d2h span per shard under the
@@ -481,5 +679,8 @@ def test_sharded_device_snapshot(sharded_snapshots, case):
     step), shared by every view; a fully replicated array and an array on
     one device are one block, adopted and never assembled; a (2, 2) mesh
     fetches each distinct block once; a write through the Dataset leaves
-    the device array and sibling slabs as they were."""
+    the device array and sibling slabs as they were.  A deferred snapshot
+    fetches nothing at write; the first of two concurrent readers (whole,
+    slab, write, spill) fetches each shard once, and a deleted array
+    raises."""
     assert sharded_snapshots[case] == "ok", sharded_snapshots[case]
